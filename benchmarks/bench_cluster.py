@@ -70,8 +70,6 @@ from __future__ import annotations
 
 import argparse
 import multiprocessing as mp
-import os
-import tempfile
 import threading
 import time
 
@@ -267,10 +265,8 @@ def _procs_shard_main(conn, barrier, algo_name, num_workers, k, reps,
     threaded sweep uses, takes its own shard's fused pass, and times
     ``reps`` applications per barrier-synced trial."""
     try:
-        from repro.cluster.procs import _enable_jax_cache
-        _enable_jax_cache(os.environ.get(
-            "REPRO_JAX_CACHE_DIR",
-            os.path.join(tempfile.gettempdir(), "repro-jax-cache")))
+        from repro.launch.cache import enable_compile_cache
+        enable_compile_cache()
         params0, grad_fn, next_batch = _setup(width=width)
         algo = make_algorithm(algo_name, HP)
         master = ShardedMaster(algo, algo.init(params0, num_workers),
@@ -316,6 +312,8 @@ def procs_capacity_row(algo_name: str, num_workers: int, k: int,
     barrier-synced across processes; the per-trial time is the slowest
     shard's (the shard servers advance in lockstep in the real runtime),
     and the row records the best trial."""
+    from repro.cluster.procs import require_host_backend
+    require_host_backend()
     ctx = mp.get_context("spawn")
     barrier = ctx.Barrier(shards + 1)
     conns, procs = [], []
@@ -437,20 +435,29 @@ def memtier_rows_for(n: int, k: int = 8, rows: int = 256, reps: int = 6,
                 use_pallas=True, prefetch=True)
         fn = (flat_master_update_batch_prefetch if path == "prefetch"
               else flat_master_update_batch_2d)
-        return fn(*args, nesterov=False, telemetry=False, interpret=True)
+        return fn(*args, nesterov=False, telemetry=False,
+                  interpret=jax.default_backend() != "tpu")
 
     routed = prefetch_pays(rows, n, k)
-    out_rows = []
-    for path in ("memtier", "prefetch", "full_slab"):
-        out = _call(path)
-        jax.block_until_ready(out[0])
-        dt = float("inf")                                # best of 3
-        for _ in range(3):
+    paths = ("memtier", "prefetch", "full_slab")
+    for path in paths:
+        jax.block_until_ready(_call(path)[0])            # compile
+    # best of 10 trials, interleaved across paths so that drift in the
+    # host's speed lands on every path alike (a trial is ~reps kernel
+    # calls, a few ms each on a CPU: a few trials can all catch a load
+    # spike on a shared host)
+    best = dict.fromkeys(paths, float("inf"))
+    for _ in range(10):
+        for path in paths:
             t0 = time.perf_counter()
             for _ in range(reps):
                 out = _call(path)
             jax.block_until_ready(out[0])
-            dt = min(dt, (time.perf_counter() - t0) / reps)
+            best[path] = min(best[path],
+                             (time.perf_counter() - t0) / reps)
+    out_rows = []
+    for path in paths:
+        dt = best[path]
         streams_pf = path == "prefetch" or (path == "memtier" and routed)
         out_rows.append({
             "section": "memtier", "n": n, "k": k, "u": u, "rows": rows,

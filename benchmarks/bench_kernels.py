@@ -110,10 +110,10 @@ def batched_update_row(rows: int, n_workers: int, k: int):
     t_seq = _time(seq, theta, v, v0, g)
     t_bat = _time(bat, theta, v, v0, g)
 
-    # interpret-mode correctness of the batched Pallas kernel
+    # correctness of the batched Pallas kernel (interpret mode off-TPU)
     outs_k = flat_master_update_batch_2d(
         theta, v, v0, None, None, g, ids, lrs, lrs, gammas, cgs, vscales,
-        nesterov=False, interpret=True)
+        nesterov=False, interpret=jax.default_backend() != "tpu")
     outs_r = bat(theta, v, v0, g)
     err = max(float(jnp.max(jnp.abs(a - b)))
               for a, b in zip(outs_k[:3] + (outs_k[5],),

@@ -174,6 +174,44 @@ def run_serve_loop(server):
                 m.respond(None)
 
 
+def fused_flat_program(fa, k: int, telemetry: bool):
+    """The master's fused receive for a k-message drain of
+    ``FlatAlgorithm`` ``fa``: ``jit(flat, ids, nows, g_flat, views) ->
+    (flat, views, gaps, gnorms[, staleness])``, state donated.  ONE
+    batched flat kernel for the whole drain.
+
+    Everything on the wire is already flat, and the batch arrives
+    STACKED: ``g_flat`` (and ``views`` under telemetry) is one
+    (k, R, 128) buffer — the caller stacks outside the jit (a single
+    dispatch on the threaded backend; the process backend stages the
+    k shared-memory grads into one host buffer and ships ONE
+    transfer).  The returned views are raw (R, 128) hat rows — the
+    master thread does no pytree work at all.
+    """
+    inv_sqrt_p = 1.0 / float(np.sqrt(fa.spec.n_elems))
+
+    def fused(flat, ids, nows, g_flat, views):
+        # per-message sent-snapshot staleness comes from the scalar
+        # lane, read BEFORE apply_batch consumes the donated state
+        # (None for snapshot-free members)
+        stals = (fa.batch_staleness(flat, ids, k) if telemetry
+                 else None)
+        flat, hats, pres = fa.apply_batch(flat, ids, g_flat, nows,
+                                          telemetry=telemetry)
+        out_views = tuple(hats[j] for j in range(k))
+        if telemetry:
+            d = pres - views             # zero in the padding region
+            gaps = jnp.sqrt(jnp.sum(d * d, axis=(1, 2))) * inv_sqrt_p
+            gnorms = jnp.sqrt(jnp.sum(g_flat * g_flat, axis=(1, 2)))
+            return flat, out_views, gaps, gnorms, stals
+        return flat, out_views, None, None
+
+    # the flat state is donated: the batched kernel aliases its state
+    # inputs to its outputs (input_output_aliases), so the update
+    # runs in place — callers rebind to the returned state
+    return jax.jit(fused, donate_argnums=(0,))
+
+
 class Master:
     def __init__(self, algo: Algorithm, state: dict, *,
                  mailbox: Mailbox, history: History, stop: threading.Event,
@@ -305,38 +343,35 @@ class Master:
     def warm(self, hot_ranges: tuple = ()):
         """Pre-compile every fused-receive variant the drain policy can
         produce (powers of two up to the coalesce window) so no compile
-        lands mid-run.  Zero gradients, discarded output state.
+        lands mid-run.  Compile only: the variants are lowered against
+        the live state and abstract batches, so warming runs nothing and
+        holds no second copy of the state on the device.
 
         ``hot_ranges`` — the distinct ``ClusterConfig.hot_rows`` (r0, r1)
         ranges workers declared: their row-sliced view closures
         (``_view_rows_jit``) are compiled here too, so the first hot-row
         pull never traces mid-run (snapshot-free families only — the
         sent family always serves full-range pulls)."""
+        def abstract(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype)
+
         if self.state_is_flat:
-            view = self._flat_state["theta"]
+            theta = self._flat_state["theta"]
         else:
-            zero_grad = jax.tree.map(jnp.zeros_like, self.master_params())
-            view = self.master_params()
+            params = jax.tree.map(abstract, self.master_params())
         k = 1
         while k <= self.coalesce:
-            ids = jnp.zeros((k,), jnp.int32)
-            nows = jnp.zeros((k,), jnp.float32)
+            ids = jax.ShapeDtypeStruct((k,), jnp.int32)
+            nows = jax.ShapeDtypeStruct((k,), jnp.float32)
             if self.state_is_flat:
                 # stacked wire format: one (k, R, 128) buffer per batch
-                grads = jnp.zeros((k,) + view.shape, view.dtype)
-                views = (jnp.broadcast_to(view, grads.shape)
-                         if self.record_telemetry else None)
+                grads = jax.ShapeDtypeStruct((k,) + theta.shape,
+                                             theta.dtype)
             else:
-                grads = tuple(zero_grad for _ in range(k))
-                views = (tuple(view for _ in range(k))
-                         if self.record_telemetry else None)
+                grads = tuple(params for _ in range(k))
+            views = grads if self.record_telemetry else None
             fn, st = self._fused_for(k, self.record_telemetry)
-            if self.state_is_flat:
-                # the fused flat pass donates its state argument; warm
-                # on a copy so the live state's buffers survive
-                st = jax.tree.map(jnp.copy, st)
-            out = fn(st, ids, nows, grads, views)
-            jax.block_until_ready(jax.tree.leaves(out[0])[0])
+            fn.lower(st, ids, nows, grads, views).compile()
             k *= 2
         if self.state_is_flat and not self._sent_family:
             for r0, r1 in hot_ranges:
@@ -350,44 +385,11 @@ class Master:
         return self._get_fused(k, telemetry), self._tree_state
 
     def _get_fused_flat(self, k: int, telemetry: bool):
-        """ONE batched flat kernel for the whole k-message drain.
-
-        Everything on the wire is already flat, and the batch arrives
-        STACKED: ``g_flat`` (and ``views`` under telemetry) is one
-        (k, R, 128) buffer — the caller stacks outside the jit (a single
-        dispatch on the threaded backend; the process backend stages the
-        k shared-memory grads into one host buffer and ships ONE
-        transfer).  The returned views are raw (R, 128) hat rows — the
-        master thread does no pytree work at all.
-        """
         key = ("flat", k, telemetry)
         fn = self._fused.get(key)
-        if fn is not None:
-            return fn
-        fa = self._flat_algo
-        inv_sqrt_p = 1.0 / float(np.sqrt(fa.spec.n_elems))
-
-        def fused(flat, ids, nows, g_flat, views):
-            # per-message sent-snapshot staleness comes from the scalar
-            # lane, read BEFORE apply_batch consumes the donated state
-            # (None for snapshot-free members)
-            stals = (fa.batch_staleness(flat, ids, k) if telemetry
-                     else None)
-            flat, hats, pres = fa.apply_batch(flat, ids, g_flat, nows,
-                                              telemetry=telemetry)
-            out_views = tuple(hats[j] for j in range(k))
-            if telemetry:
-                d = pres - views             # zero in the padding region
-                gaps = jnp.sqrt(jnp.sum(d * d, axis=(1, 2))) * inv_sqrt_p
-                gnorms = jnp.sqrt(jnp.sum(g_flat * g_flat, axis=(1, 2)))
-                return flat, out_views, gaps, gnorms, stals
-            return flat, out_views, None, None
-
-        # the flat state is donated: the batched kernel aliases its state
-        # inputs to its outputs (input_output_aliases), so the update
-        # runs in place — callers rebind to the returned state
-        fn = jax.jit(fused, donate_argnums=(0,))
-        self._fused[key] = fn
+        if fn is None:
+            fn = self._fused[key] = fused_flat_program(self._flat_algo, k,
+                                                       telemetry)
         return fn
 
     def _get_fused(self, k: int, telemetry: bool):

@@ -47,10 +47,8 @@ message schedule — the bit-exact equivalence harness.
 from __future__ import annotations
 
 import math
-import os
 import pickle
 import sys
-import tempfile
 import time
 import traceback
 from collections import deque
@@ -422,23 +420,6 @@ def _attach(name: str):
     return shm
 
 
-def _enable_jax_cache(path):
-    """Point the child at a shared persistent compilation cache so the
-    spawn-per-shard model does not pay the full XLA compile in every
-    process (compiles in children dominate small-run wall time
-    otherwise).  Best-effort: older jax builds without CPU-cache support
-    just compile as usual."""
-    if not path:
-        return
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", str(path))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.0)
-    except Exception:  # noqa: BLE001 - cache is a pure optimization
-        pass
-
-
 def _gate_acquire(ctl, wid: int, n: int, stop: _ShmStop) -> bool:
     spins = 0
     while int(ctl[C_TURN]) % n != wid:
@@ -643,10 +624,11 @@ def server_main(conn, shm_name, layout, sid, job):
         from ..core.flat import FlatSpec
         from ..kernels.flat_update import FlatAlgorithm
         from ..obs.metrics import MetricsRegistry, serve_instruments
+        from ..launch.cache import enable_compile_cache
         from .faults import FaultInjector
         from .master import run_serve_loop
 
-        _enable_jax_cache(job.get("jax_cache"))
+        enable_compile_cache()
         shm = _attach(shm_name)
         buf = shm.buf
         ctl_i = layout.ctl_i(buf)
@@ -752,9 +734,10 @@ def worker_main(conn, shm_name, layout, lock, wid, job):
     try:
         import jax
         from ..core.flat import FlatSpec
+        from ..launch.cache import enable_compile_cache
         from .faults import FaultInjector
 
-        _enable_jax_cache(job.get("jax_cache"))
+        enable_compile_cache()
         shm = _attach(shm_name)
         buf = shm.buf
         ctl_i = layout.ctl_i(buf)
@@ -916,9 +899,24 @@ def _check_picklable(grad_fn, next_batch):
                 f"closure.") from e
 
 
+def require_host_backend():
+    """Refuse to start JAX children from a process that holds a TPU.
+
+    The parent runs JAX before it spawns, and every child imports JAX;
+    only one process may hold a TPU chip, so on a TPU host the children
+    would fail or hang waiting for it."""
+    import jax
+    if jax.default_backend() == "tpu":
+        raise ValueError(
+            "backend='process' starts JAX in the parent and in every "
+            "child process, but only one process may hold a TPU chip; "
+            "use the threaded backend (backend='thread') on a TPU host")
+
+
 def validate_process_config(algo, cfg):
     """The process backend's support matrix (README "Backends")."""
     from ..kernels.flat_update import family_spec_for, kernel_eligible
+    require_host_backend()
     if cfg.mode == "deterministic":
         raise ValueError("backend='process' supports live modes only "
                          "(paced/free); deterministic replay needs the "
@@ -1020,23 +1018,19 @@ def run_cluster_procs(algo, grad_fn, params0, next_batch, cfg,
     inv_sqrt_p = 1.0 / math.sqrt(spec.n_elems)
     sent_family = fam.stateful_send
 
-    jax_cache = os.environ.get(
-        "REPRO_JAX_CACHE_DIR",
-        os.path.join(tempfile.gettempdir(), "repro-jax-cache"))
     server_job_base = dict(
         algo=algo, params0=params0_np, total=cfg.total_grads,
         coalesce=coalesce, telemetry=telemetry,
         eval_boundary=eval_boundary, eval_every=max(1, cfg.eval_every),
         has_eval=eval_fn is not None, faults=cfg.faults,
-        mean_iter_time=mean_iter, steady_mark=steady_mark,
-        jax_cache=jax_cache)
+        mean_iter_time=mean_iter, steady_mark=steady_mark)
     worker_job_base = dict(
         grad_fn=grad_fn, next_batch=next_batch, params0=params0_np,
         faults=cfg.faults, mean_iter_time=mean_iter, mode=cfg.mode,
         exec_model=cfg.exec_model, time_scale=cfg.time_scale,
         telemetry=telemetry, rpc_timeout=cfg.rpc_timeout,
         pin_schedule=cfg.pin_schedule, total=cfg.total_grads,
-        pipeline_depth=cfg.pipeline_depth, jax_cache=jax_cache)
+        pipeline_depth=cfg.pipeline_depth)
 
     servers, workers = [], []
     server_conns, worker_conns = [], []

@@ -82,6 +82,14 @@ class ClusterConfig:
     pipeline_depth: int = 0
 
 
+def flat_grad_program(spec, grad_fn, donate=()):
+    """The worker's program on the flat wire: unpack the (R, 128) view,
+    take the gradient, ``pack_fused`` it into the (R, 128) wire — one
+    jit, ``jit(view, batch) -> wire``; ``donate=(0,)`` donates the view."""
+    return jax.jit(lambda fv, batch: spec.pack_fused(
+        grad_fn(spec.unpack(fv), batch)), donate_argnums=donate)
+
+
 def run_cluster(
     algo: Algorithm,
     grad_fn: Callable[[Pytree, Any], Pytree],
@@ -225,6 +233,10 @@ def run_cluster(
             use_kernel=use_kernel, record_telemetry=cfg.record_telemetry,
             eval_fn=eval_fn, eval_every=cfg.eval_every, injector=injector,
             time_fn=time_fn, pipeline_depth=cfg.pipeline_depth)
+    # the master packed (or adopted) the algorithm state; dropping this
+    # reference frees the pytree copy, which at real widths is
+    # (N + 2) parameter copies of device memory
+    del state
 
     # -- observability wiring (None-guarded: zero hot-path cost when off)
     publisher = None
@@ -318,9 +330,8 @@ def run_cluster(
         # emits its packed gradient inside ITS OWN jit (the fused
         # backward->wire pack) — the pytree<->flat traffic runs on the
         # (parallel) worker threads, never on the master hot path
-        spec = master._flat_algo.spec
-        grad_jit = jax.jit(lambda fv, batch: spec.pack_fused(
-            grad_fn(spec.unpack(fv), batch)), donate_argnums=donate)
+        grad_jit = flat_grad_program(master._flat_algo.spec, grad_fn,
+                                     donate)
     else:
         # tree path: views ALIAS master state (send returns theta0
         # itself), so donation is never safe here

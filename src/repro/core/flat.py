@@ -10,6 +10,10 @@ kernel over one contiguous buffer.
 
 Layout: all leaves raveled in treedef order, concatenated, zero-padded to
 a whole number of 128-lane rows (TPU lane dimension), viewed as (R, 128).
+R is a multiple of 8 (TPU sublanes) and, once the state is taller than
+one kernel row tile (``TILE_ROWS``), a multiple of that tile, so the flat
+kernels always run full-height tiles (at most ``TILE_ROWS - 1`` rows of
+padding).
 Per-worker stacked state (leaves shaped (N, ...)) packs to (N, R, 128)
 with the SAME per-row layout, so row r of worker i's slab and row r of
 theta describe the same parameters.
@@ -59,6 +63,9 @@ import jax
 import jax.numpy as jnp
 
 LANES = 128
+# the flat kernels' row tile (kernels/flat_update BLOCK_ROWS): states
+# taller than one tile pad to whole tiles
+TILE_ROWS = 256
 
 
 class FlatSpec:
@@ -66,17 +73,22 @@ class FlatSpec:
 
     Built once from a template tree; ``pack``/``unpack`` are then pure
     reshape/concat/split traffic with no host-side tree walking beyond
-    the (static) leaf list.
+    the (static) leaf list.  ``row_align`` (rows pad to its multiples;
+    shard boundaries snap to it) defaults to 8, or to ``TILE_ROWS`` for
+    a state taller than one tile.
     """
 
-    def __init__(self, treedef, shapes, dtypes, *, row_align: int = 8):
+    def __init__(self, treedef, shapes, dtypes, *,
+                 row_align: int | None = None):
         self.treedef = treedef
         self.shapes = tuple(tuple(s) for s in shapes)
         self.dtypes = tuple(dtypes)
         self.sizes = tuple(int(math.prod(s)) for s in self.shapes)
         self.n_elems = int(sum(self.sizes))
-        self.row_align = int(row_align)
         rows = -(-self.n_elems // LANES)
+        if row_align is None:
+            row_align = TILE_ROWS if rows > TILE_ROWS else 8
+        self.row_align = int(row_align)
         self.rows = -(-rows // row_align) * row_align
         self.padded = self.rows * LANES
         offs, o = [], 0
@@ -86,7 +98,8 @@ class FlatSpec:
         self.offsets = tuple(offs)
 
     @classmethod
-    def from_tree(cls, tree, *, row_align: int = 8) -> "FlatSpec":
+    def from_tree(cls, tree, *,
+                  row_align: int | None = None) -> "FlatSpec":
         leaves, treedef = jax.tree.flatten(tree)
         return cls(treedef, [l.shape for l in leaves],
                    [l.dtype for l in leaves], row_align=row_align)

@@ -39,7 +39,7 @@ def _kernel(scal_ref, theta_ref, vi_ref, v0_ref, g_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def dana_master_update_2d(theta, v_i, v0, g, lr, gamma, *, interpret=True):
+def dana_master_update_2d(theta, v_i, v0, g, lr, gamma, *, interpret=False):
     """theta/v_i/v0/g: (R, 128) float arrays; lr/gamma scalars."""
     r, lanes = theta.shape
     # NOTE: these used to be one chained assert whose `and`/`or` precedence

@@ -51,20 +51,24 @@ Two lowerings cover the elementwise family:
   the grid instead of N slabs resident per tile), which reorders the
   reduction — views agree to tolerance, state stays bit-exact.
 
-Scalars ride in as an (8, k) f32 tile — worker id, lr(t+j), gamma,
-grad-coef, momentum-correction vscale, and the per-message hat
-coefficient hc_j (the send scale at the post-update step, which is
-where lr(t+j+1) enters; rows 6-7 padding); ids are exact in f32 below
-2^24 workers.  Feeding the schedule as per-message scalars is what
-lifts the constant-lr restriction; hc_j is what generalizes the
-look-ahead beyond the v0 running sum:
+Per-message scalars live in SMEM, where the kernel may index them by
+the grid's message coordinate: a (5, k) f32 table — lr(t+j), gamma,
+grad-coef, momentum-correction vscale, and the hat coefficient hc_j
+(the send scale at the post-update step, which is where lr(t+j+1)
+enters) — plus the (k,) int32 worker ids (the prefetch kernel reads its
+window slots from the scalar-prefetch schedule instead).  A VMEM tile
+indexed at a dynamic lane would not lower on the TPU.  Feeding the
+schedule as per-message scalars is what lifts the constant-lr
+restriction; hc_j is what generalizes the look-ahead beyond the v0
+running sum:
 
     hat_mode "theta"      hat_j = theta'                  (plain senders)
     hat_mode "v0"         hat_j = theta' - hc_j*v0' [/den]  (dana/nadam)
     hat_mode "self"       hat_j = theta' - hc_j*v_i'        (lwp)
     hat_mode "weighted"   hat_j = theta' - hc_j*sum_m w_jm v_m'
                           (dana-hetero: the in-kernel weighted-slab
-                          reduction; w streams in as a (k, N) tile)
+                          reduction; w rides in as a (k, N) SMEM table
+                          and the N slab rows accumulate one by one)
 
 The batched kernel covers exactly the ELEMENTWISE family (incl. delay
 compensation and the weighted hat, which are elementwise per row).  The
@@ -93,11 +97,10 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...core.flat import LANES, TILE_ROWS as BLOCK_ROWS
 from .ref import default_hat_coefs
 
-BLOCK_ROWS = 256
-LANES = 128
-SCAL_ROWS = 8              # (8, k) scalar tile: f32 sublane alignment
+STAT_ROWS = 8              # gap kernel's (8, 128) avg_step output tile
 # VMEM budget for the (N, block_rows, 128) slabs: in + out copies at 4
 # bytes per slab, keep n_slabs * N * block_rows under ~8k rows (~8 MB).
 _MAX_SLAB_ROWS = 8192
@@ -109,7 +112,14 @@ def _pick_block_rows(r: int, window: int, n_slabs: int = 1) -> int:
     SLAB: the full-slab kernel passes N (every worker row streams), the
     prefetch kernel passes k + 2 (the k-slot scratch window plus the
     in/out blocks) — so its budget scales with the batch, never with
-    the worker count."""
+    the worker count.  The send kernel passes its slab height N.
+
+    The tile is R itself when R fits the cap, else the largest multiple
+    of 8 that divides R: the TPU lowering takes a block's second-minor
+    dimension only as a multiple of 8 or the full dimension.  ``FlatSpec``
+    pads R to a multiple of 8, and a state taller than one tile to a
+    multiple of ``BLOCK_ROWS``, so its states always tile, and at full
+    height wherever the cap is ``BLOCK_ROWS``."""
     cap = min(BLOCK_ROWS,
               (_MAX_SLAB_ROWS // max(window * n_slabs, 1)) // 8 * 8)
     if cap < 8:
@@ -121,19 +131,34 @@ def _pick_block_rows(r: int, window: int, n_slabs: int = 1) -> int:
             f"rows); shard the master or use the tree path")
     if r <= cap:
         return r
-    for d in range(cap, 0, -1):
+    for d in range(cap, 7, -8):
         if r % d == 0:
             return d
-    return r
+    raise ValueError(
+        f"{r} rows have no row tile that is a multiple of 8 and at most "
+        f"{cap}; pad the rows to a multiple of 8 (FlatSpec does)")
+
+
+def _smem():
+    """Whole-array SMEM operand: per-message scalars the kernel indexes
+    by a grid coordinate."""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _scalar_table(lrs, gammas, cgs, vscales, hcs):
+    """The (5, k) f32 per-message scalar table (rows: lr, gamma, cg,
+    vscale, hc)."""
+    return jnp.stack([jnp.asarray(x, jnp.float32)
+                      for x in (lrs, gammas, cgs, vscales, hcs)])
 
 
 def _make_kernel(nesterov: bool, track_v0: bool, adaptive: bool,
                  track_sent: bool, b2: float, eps: float,
                  dc_lambda: float | None, sent_view: bool,
-                 hat_mode: str, telemetry: bool):
+                 hat_mode: str, telemetry: bool, n_workers: int):
     def kernel(*refs):
         it = iter(refs)
-        scal_ref = next(it)
+        ids_ref, scal_ref = next(it), next(it)
         w_ref = next(it) if hat_mode == "weighted" else None
         theta_ref, v_ref = next(it), next(it)
         v0_ref = next(it) if track_v0 else None
@@ -148,12 +173,12 @@ def _make_kernel(nesterov: bool, track_v0: bool, adaptive: bool,
         pre_o = next(it) if telemetry else None
 
         j = pl.program_id(1)
-        i = scal_ref[0, j].astype(jnp.int32)
-        lr = scal_ref[1, j]
-        gamma = scal_ref[2, j]
-        cg = scal_ref[3, j]
-        vs = scal_ref[4, j]
-        hc = scal_ref[5, j]
+        i = ids_ref[j]
+        lr = scal_ref[0, j]
+        gamma = scal_ref[1, j]
+        cg = scal_ref[2, j]
+        vs = scal_ref[3, j]
+        hc = scal_ref[4, j]
 
         @pl.when(j == 0)
         def _seed_state():
@@ -209,8 +234,9 @@ def _make_kernel(nesterov: bool, track_v0: bool, adaptive: bool,
         elif hat_mode == "self":
             hat = (-hc) * v_new + theta
         else:                                    # "weighted"
-            wj = w_ref[pl.ds(j, 1), :][0]        # (N,)
-            wsum = jnp.sum(wj[:, None, None] * v_o[...], axis=0)
+            wsum = w_ref[j, 0] * v_o[0]
+            for m in range(1, n_workers):
+                wsum = wsum + w_ref[j, m] * v_o[m]
             hat = (-hc) * wsum + theta
         hat_o[...] = hat[None]
         if track_sent:
@@ -232,7 +258,7 @@ def flat_master_update_batch_2d(theta, v, v0, u2, sent, g, ids, lrs,
                                 hat_mode: str | None = None,
                                 hcs=None, weights=None,
                                 telemetry: bool = False,
-                                interpret: bool = True):
+                                interpret: bool = False):
     """Batched flat master update (see ref.py for the update rule; this
     lowering covers the elementwise family — no gap-aware penalty).
 
@@ -260,25 +286,17 @@ def flat_master_update_batch_2d(theta, v, v0, u2, sent, g, ids, lrs,
 
     # lrs_next itself never enters the kernel: its only consumer is the
     # hat coefficient, folded into hcs above
-    scal = jnp.zeros((SCAL_ROWS, k), jnp.float32)
-    scal = scal.at[:6].set(jnp.stack([
-        ids.astype(jnp.float32),
-        jnp.asarray(lrs, jnp.float32),
-        jnp.asarray(gammas, jnp.float32),
-        jnp.asarray(cgs, jnp.float32),
-        jnp.asarray(vscales, jnp.float32),
-        jnp.asarray(hcs, jnp.float32)]))               # (8, k)
+    scal = _scalar_table(lrs, gammas, cgs, vscales, hcs)
 
     flat_spec = pl.BlockSpec((block_r, LANES), lambda ri, j: (ri, 0))
     slab_spec = pl.BlockSpec((n, block_r, LANES), lambda ri, j: (0, ri, 0))
     msg_spec = pl.BlockSpec((1, block_r, LANES), lambda ri, j: (j, ri, 0))
-    scal_spec = pl.BlockSpec((SCAL_ROWS, k), lambda ri, j: (0, 0))
 
     f32 = jnp.float32
-    in_specs = [scal_spec]
-    inputs = [scal]
+    in_specs = [_smem(), _smem()]
+    inputs = [jnp.asarray(ids, jnp.int32), scal]
     if hat_mode == "weighted":
-        in_specs.append(pl.BlockSpec((k, n), lambda ri, j: (0, 0)))
+        in_specs.append(_smem())
         inputs.append(jnp.asarray(weights, f32))
     # state inputs alias their outputs: with donated caller buffers the
     # batch updates the master state in place (no-copy tested)
@@ -319,7 +337,7 @@ def flat_master_update_batch_2d(theta, v, v0, u2, sent, g, ids, lrs,
 
     outs = pl.pallas_call(
         _make_kernel(nesterov, track_v0, adaptive, track_sent, b2, eps,
-                     dc_lambda, sent_view, hat_mode, telemetry),
+                     dc_lambda, sent_view, hat_mode, telemetry, n),
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
@@ -375,7 +393,7 @@ def _prefetch_schedule(ids, k: int):
 def _make_prefetch_kernel(nesterov: bool, track_v0: bool, adaptive: bool,
                           track_sent: bool, b2: float, eps: float,
                           dc_lambda: float | None, sent_view: bool,
-                          hat_mode: str, telemetry: bool):
+                          hat_mode: str, telemetry: bool, n_window: int):
     weighted = hat_mode == "weighted"
 
     def kernel(*refs):
@@ -402,11 +420,11 @@ def _make_prefetch_kernel(nesterov: bool, track_v0: bool, adaptive: bool,
         j = pl.program_id(1)
         slot = sched_ref[2, j]
         wslot = sched_ref[3, j]
-        lr = scal_ref[1, j]
-        gamma = scal_ref[2, j]
-        cg = scal_ref[3, j]
-        vs = scal_ref[4, j]
-        hc = scal_ref[5, j]
+        lr = scal_ref[0, j]
+        gamma = scal_ref[1, j]
+        cg = scal_ref[2, j]
+        vs = scal_ref[3, j]
+        hc = scal_ref[4, j]
 
         @pl.when(j == 0)
         def _seed_state():
@@ -474,9 +492,10 @@ def _make_prefetch_kernel(nesterov: bool, track_v0: bool, adaptive: bool,
         elif hat_mode == "self":
             hat = (-hc) * v_new + theta
         else:                                    # "weighted"
-            wj = ww_ref[pl.ds(j, 1), :][0]       # (k,) window weights
-            wsum = base_ref[...][0] + jnp.sum(
-                wj[:, None, None] * (v_scr[...] - orig_scr[...]), axis=0)
+            # base_j + the window's deltas, one slot at a time
+            wsum = base_ref[...][0]
+            for m in range(n_window):
+                wsum = wsum + ww_ref[j, m] * (v_scr[m] - orig_scr[m])
             hat = (-hc) * wsum + theta
         hat_o[...] = hat[None]
         if track_sent:
@@ -503,7 +522,7 @@ def flat_master_update_batch_prefetch(theta, v, v0, u2, sent, g, ids, lrs,
                                       hat_mode: str | None = None,
                                       hcs=None, weights=None,
                                       telemetry: bool = False,
-                                      interpret: bool = True):
+                                      interpret: bool = False):
     """Batched flat master update, scalar-prefetch memory tier: same
     contract as ``flat_master_update_batch_2d`` (bit-exact for every
     non-weighted hat; the weighted view agrees to reduction-order
@@ -530,14 +549,7 @@ def flat_master_update_batch_prefetch(theta, v, v0, u2, sent, g, ids, lrs,
     grid = (r // block_r, k)
 
     sched = _prefetch_schedule(ids, k)
-    scal = jnp.zeros((SCAL_ROWS, k), jnp.float32)
-    scal = scal.at[:6].set(jnp.stack([
-        jnp.asarray(ids, jnp.float32),
-        jnp.asarray(lrs, jnp.float32),
-        jnp.asarray(gammas, jnp.float32),
-        jnp.asarray(cgs, jnp.float32),
-        jnp.asarray(vscales, jnp.float32),
-        jnp.asarray(hcs, jnp.float32)]))               # (8, k)
+    scal = _scalar_table(lrs, gammas, cgs, vscales, hcs)
 
     # index maps see the grid indices then the scalar-prefetch ref: the
     # slab specs pick ONE worker row per step from the schedule
@@ -548,10 +560,9 @@ def flat_master_update_batch_prefetch(theta, v, v0, u2, sent, g, ids, lrs,
                             lambda ri, j, s: (s[1, j], ri, 0))
     msg_spec = pl.BlockSpec((1, block_r, LANES),
                             lambda ri, j, s: (j, ri, 0))
-    scal_spec = pl.BlockSpec((SCAL_ROWS, k), lambda ri, j, s: (0, 0))
 
     f32 = jnp.float32
-    in_specs = [scal_spec]
+    in_specs = [_smem()]
     inputs = [sched, scal]                        # sched counts in aliases
     if weighted:
         w = jnp.asarray(weights, f32)
@@ -560,7 +571,7 @@ def flat_master_update_batch_prefetch(theta, v, v0, u2, sent, g, ids, lrs,
         ww = jnp.take(w, jnp.asarray(ids, jnp.int32), axis=1) \
             * sched[4].astype(f32)[None, :]
         base = jnp.tensordot(w, v, axes=([1], [0]))
-        in_specs.append(pl.BlockSpec((k, k), lambda ri, j, s: (0, 0)))
+        in_specs.append(_smem())
         inputs.append(ww)
     # state inputs alias their outputs: with donated caller buffers the
     # batch updates in place, and slab blocks no schedule entry ever
@@ -619,7 +630,7 @@ def flat_master_update_batch_prefetch(theta, v, v0, u2, sent, g, ids, lrs,
     outs = pl.pallas_call(
         _make_prefetch_kernel(nesterov, track_v0, adaptive, track_sent,
                               b2, eps, dc_lambda, sent_view, hat_mode,
-                              telemetry),
+                              telemetry, k),
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=aliases,
@@ -655,13 +666,12 @@ def gap_pallas_supported(rows: int, n: int, prefetch: bool = False) -> bool:
 
 
 def _make_gap_kernel(gap_ema: float, sqrt_p: float, telemetry: bool,
-                     prefetch: bool = False):
+                     prefetch: bool):
     def kernel(*refs):
         it = iter(refs)
-        if prefetch:
-            next(it)                             # scalar-prefetch ids ref
-        scal_ref, theta_ref, v_ref, sent_ref, g_ref = (
-            next(it), next(it), next(it), next(it), next(it))
+        ids_ref, scal_ref = next(it), next(it)   # ids: scalar prefetch
+        theta_ref, v_ref, sent_ref, g_ref = (next(it), next(it), next(it),
+                                             next(it))
         theta_o, v_o, sent_o, hat_o, stat_o = (
             next(it), next(it), next(it), next(it), next(it))
         pre_o = next(it) if telemetry else None
@@ -670,21 +680,21 @@ def _make_gap_kernel(gap_ema: float, sqrt_p: float, telemetry: bool,
         ph = pl.program_id(0)
         ri = pl.program_id(1)
         nt = pl.num_programs(1)
-        i = scal_ref[0, 0].astype(jnp.int32)
-        lr = scal_ref[1, 0]
-        gamma = scal_ref[2, 0]
-        cg = scal_ref[3, 0]
-        vs = scal_ref[4, 0]
+        i = ids_ref[0]
+        lr = scal_ref[0]
+        gamma = scal_ref[1]
+        cg = scal_ref[2]
+        vs = scal_ref[3]
 
         @pl.when((ph == 0) & (ri == 0))
         def _seed():
             acc[0] = 0.0
             acc[1] = 0.0
-            acc[2] = scal_ref[5, 0]              # avg_step in
+            acc[2] = scal_ref[4]                 # avg_step in
 
         theta = theta_ref[...]
         # prefetch: the slab blocks ARE worker i's row (scalar-prefetch
-        # index maps); legacy: dynamic-slice it out of the full slab
+        # index maps); full slab: dynamic-slice it out
         si = (sent_ref[...] if prefetch
               else sent_ref[pl.ds(i, 1), :, :])[0]
 
@@ -734,8 +744,7 @@ def _make_gap_kernel(gap_ema: float, sqrt_p: float, telemetry: bool,
                 step_rms = lr * vs * jnp.sqrt(acc[1]) / sqrt_p
                 avg = gap_ema * acc[2] + (1 - gap_ema) * step_rms
                 acc[2] = avg
-                stat_o[...] = jnp.zeros(
-                    (SCAL_ROWS, LANES), jnp.float32).at[0, 0].set(avg)
+                stat_o[...] = jnp.full(stat_o.shape, avg, jnp.float32)
 
     return kernel
 
@@ -747,10 +756,11 @@ def gap_master_update_1(theta, v, sent, avg_step, g_row, i, lr, gamma,
     """ONE gap-aware message, grid (2, row_tiles) with SMEM-scratch
     norm partials.  Returns (theta', v', sent', avg_step', hat, pre).
 
-    ``prefetch`` selects worker i's v/sent rows through scalar-prefetch
-    index maps (one-row slab blocks, N-independent VMEM) and aliases the
-    state inputs to their outputs — untouched workers' rows survive
-    through the aliasing instead of full-slab passthrough writes."""
+    Worker i's id rides in as the scalar-prefetch operand.  ``prefetch``
+    selects worker i's v/sent rows through it (one-row slab blocks,
+    N-independent VMEM) and aliases the state inputs to their outputs —
+    untouched workers' rows survive through the aliasing instead of
+    full-slab passthrough writes."""
     r, lanes = theta.shape
     n = v.shape[0]
     assert lanes == LANES, lanes
@@ -759,64 +769,44 @@ def gap_master_update_1(theta, v, sent, avg_step, g_row, i, lr, gamma,
     grid = (2, nt)
     # f32-rounded like the reference's jnp.sqrt(asarray(n_elems, f32))
     sqrt_p = float(np.sqrt(np.float32(n_elems), dtype=np.float32))
-    scal = jnp.zeros((SCAL_ROWS, LANES), jnp.float32).at[:6, 0].set(
-        jnp.stack([jnp.asarray(i, jnp.float32),
-                   jnp.asarray(lr, jnp.float32),
-                   jnp.asarray(gamma, jnp.float32),
-                   jnp.asarray(cg, jnp.float32),
-                   jnp.asarray(vs, jnp.float32),
-                   jnp.asarray(avg_step, jnp.float32)]))
+    scal = jnp.stack([jnp.asarray(x, jnp.float32)
+                      for x in (lr, gamma, cg, vs, avg_step)])
+    ids = jnp.reshape(jnp.asarray(i, jnp.int32), (1,))
 
     f32 = jnp.float32
     out_shape = [jax.ShapeDtypeStruct((r, LANES), f32),
                  jax.ShapeDtypeStruct((n, r, LANES), f32),
                  jax.ShapeDtypeStruct((n, r, LANES), f32),
                  jax.ShapeDtypeStruct((r, LANES), f32),
-                 jax.ShapeDtypeStruct((SCAL_ROWS, LANES), f32)]
+                 jax.ShapeDtypeStruct((STAT_ROWS, LANES), f32)]
     if telemetry:
         out_shape.append(jax.ShapeDtypeStruct((r, LANES), f32))
+    flat_spec = pl.BlockSpec((block_r, LANES), lambda ph, ri, s: (ri, 0))
     if prefetch:
-        flat_spec = pl.BlockSpec((block_r, LANES),
-                                 lambda ph, ri, s: (ri, 0))
         slab_spec = pl.BlockSpec((1, block_r, LANES),
                                  lambda ph, ri, s: (s[0], ri, 0))
-        stat_spec = pl.BlockSpec((SCAL_ROWS, LANES),
-                                 lambda ph, ri, s: (0, 0))
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[stat_spec, flat_spec, slab_spec, slab_spec,
-                      flat_spec],
-            out_specs=[flat_spec, slab_spec, slab_spec, flat_spec,
-                       stat_spec] + ([flat_spec] if telemetry else []),
-            scratch_shapes=[pltpu.SMEM((4,), f32)])
-        sched = jnp.reshape(jnp.asarray(i, jnp.int32), (1,))
-        out = pl.pallas_call(
-            _make_gap_kernel(gap_ema, sqrt_p, telemetry, prefetch=True),
-            grid_spec=grid_spec,
-            out_shape=out_shape,
-            # theta/v/sent alias their outputs (operand indices count the
-            # scalar-prefetch ref)
-            input_output_aliases={2: 0, 3: 1, 4: 2},
-            interpret=interpret,
-        )(sched, scal, theta, v, sent, g_row)
+        # theta/v/sent alias their outputs (operand indices count the
+        # scalar-prefetch ref)
+        aliases = {2: 0, 3: 1, 4: 2}
     else:
-        flat_spec = pl.BlockSpec((block_r, LANES), lambda ph, ri: (ri, 0))
         slab_spec = pl.BlockSpec((n, block_r, LANES),
-                                 lambda ph, ri: (0, ri, 0))
-        stat_spec = pl.BlockSpec((SCAL_ROWS, LANES), lambda ph, ri: (0, 0))
-        out = pl.pallas_call(
-            _make_gap_kernel(gap_ema, sqrt_p, telemetry),
-            grid=grid,
-            in_specs=[stat_spec, flat_spec, slab_spec, slab_spec,
-                      flat_spec],
-            out_specs=[flat_spec, slab_spec, slab_spec, flat_spec,
-                       stat_spec]
-            + ([flat_spec] if telemetry else []),
-            out_shape=out_shape,
-            scratch_shapes=[pltpu.SMEM((4,), f32)],
-            interpret=interpret,
-        )(scal, theta, v, sent, g_row)
+                                 lambda ph, ri, s: (0, ri, 0))
+        aliases = {}
+    stat_spec = pl.BlockSpec((STAT_ROWS, LANES), lambda ph, ri, s: (0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=grid,
+        in_specs=[_smem(), flat_spec, slab_spec, slab_spec, flat_spec],
+        out_specs=[flat_spec, slab_spec, slab_spec, flat_spec, stat_spec]
+        + ([flat_spec] if telemetry else []),
+        scratch_shapes=[pltpu.SMEM((4,), f32)])
+    out = pl.pallas_call(
+        _make_gap_kernel(gap_ema, sqrt_p, telemetry, prefetch),
+        grid_spec=grid_spec,
+        out_shape=out_shape,
+        input_output_aliases=aliases,
+        interpret=interpret,
+    )(ids, scal, theta, v, sent, g_row)
     theta_n, v_n, sent_n, hat, stat = out[:5]
     pre = out[5] if telemetry else None
     return theta_n, v_n, sent_n, stat[0, 0], hat, pre
@@ -828,7 +818,7 @@ def gap_master_update_1(theta, v, sent, avg_step, g_row, i, lr, gamma,
 def flat_master_update_batch_gap(theta, v, sent, avg_step, g, ids, lrs,
                                  gammas, cgs, vscales, *, gap_ema: float,
                                  n_elems: int, telemetry: bool = False,
-                                 interpret: bool = True,
+                                 interpret: bool = False,
                                  prefetch: bool = False):
     """k gap-aware messages: k chained two-phase kernels in one jit
     (see module docstring for why the messages cannot share one grid).

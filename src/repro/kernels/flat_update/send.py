@@ -20,8 +20,9 @@ on its slice (``view[r0:r1] == flat_send_view(theta[r0:r1],
 slab[:, r0:r1], ...)`` bit-for-bit — property-tested), which is how the
 sharded master's sends reduce per row range.
 
-Lowering: one Pallas grid over row tiles on TPU (the slab stays resident
-per tile while the N rows reduce), the jnp reference elsewhere.  The
+Lowering: one Pallas grid over row tiles on TPU (c and the N weights
+ride in SMEM; a tile's N slab rows accumulate one by one), the jnp
+reference elsewhere.  The
 reference mirrors the tree path's ``tensordot`` + axpy expression
 bit-for-bit (that is the production jnp pairing, pinned by the
 flat == tree equivalence tests).  The Pallas lowering agrees with the
@@ -37,23 +38,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-LANES = 128
-BLOCK_ROWS = 256
-_MAX_SLAB_ROWS = 8192
+from .kernel import LANES, _pick_block_rows, _smem
 
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
-
-
-def _block_rows(r: int, n: int) -> int:
-    cap = min(BLOCK_ROWS, max((_MAX_SLAB_ROWS // max(n, 1)) // 8 * 8, 8))
-    if r <= cap:
-        return r
-    for d in range(cap, 0, -1):
-        if r % d == 0:
-            return d
-    return r
 
 
 def flat_send_view_ref(theta, slab, w, c, u2=None, eps: float = 1e-8):
@@ -64,16 +53,16 @@ def flat_send_view_ref(theta, slab, w, c, u2=None, eps: float = 1e-8):
     return (-c) * wsum + theta
 
 
-def _make_kernel(adaptive: bool, eps: float):
+def _make_kernel(adaptive: bool, eps: float, n: int):
     def kernel(*refs):
         it = iter(refs)
-        scal_ref, w_ref, theta_ref, slab_ref = (next(it), next(it),
-                                                next(it), next(it))
+        scal_ref, theta_ref, slab_ref = next(it), next(it), next(it)
         u2_ref = next(it) if adaptive else None
         out_ref = next(it)
-        c = scal_ref[0, 0]
-        wj = w_ref[0, :]                              # (N,)
-        wsum = jnp.sum(wj[:, None, None] * slab_ref[...], axis=0)
+        c = scal_ref[0]                               # SMEM: c, w_0..w_N-1
+        wsum = scal_ref[1] * slab_ref[0]
+        for m in range(1, n):
+            wsum = wsum + scal_ref[1 + m] * slab_ref[m]
         if adaptive:
             out_ref[...] = theta_ref[...] \
                 - (c * wsum) / (jnp.sqrt(u2_ref[...]) + eps)
@@ -88,23 +77,21 @@ def _send_view_pallas(theta, slab, w, c, u2, *, eps: float,
     r, lanes = theta.shape
     n = slab.shape[0]
     assert lanes == LANES, lanes
-    block_r = _block_rows(r, n)
+    block_r = _pick_block_rows(r, n)
     grid = (r // block_r,)
-    scal = jnp.zeros((1, LANES), jnp.float32).at[0, 0].set(c)
-    w_in = jnp.asarray(w, jnp.float32)[None]          # (1, N)
+    scal = jnp.concatenate([jnp.reshape(c, (1,)), w])    # (1 + N,) SMEM
 
     flat_spec = pl.BlockSpec((block_r, LANES), lambda ri: (ri, 0))
-    in_specs = [pl.BlockSpec((1, LANES), lambda ri: (0, 0)),
-                pl.BlockSpec((1, n), lambda ri: (0, 0)),
+    in_specs = [_smem(),
                 flat_spec,
                 pl.BlockSpec((n, block_r, LANES), lambda ri: (0, ri, 0))]
-    inputs = [scal, w_in, theta, slab]
+    inputs = [scal, theta, slab]
     adaptive = u2 is not None
     if adaptive:
         in_specs.append(flat_spec)
         inputs.append(u2)
     return pl.pallas_call(
-        _make_kernel(adaptive, eps),
+        _make_kernel(adaptive, eps, n),
         grid=grid,
         in_specs=in_specs,
         out_specs=flat_spec,

@@ -46,7 +46,7 @@ def _kernel(x_ref, d_ref, b_ref, c_ref, a_ref, h0_ref, y_ref, last_ref):
 @functools.partial(jax.jit,
                    static_argnames=("seq_chunk", "chan_tile", "interpret"))
 def mamba_scan_pallas(x, delta, b, c, a, h0, *, seq_chunk=64,
-                      chan_tile=LANES, interpret=True):
+                      chan_tile=LANES, interpret=False):
     bsz, s, d = x.shape
     n = a.shape[1]
     seq_chunk = min(seq_chunk, s)

@@ -40,7 +40,7 @@ def _kernel(a_ref, x_ref, h0_ref, out_ref, last_ref, *, seq_chunks):
 @functools.partial(jax.jit,
                    static_argnames=("seq_chunk", "chan_tile", "interpret"))
 def rglru_scan_pallas(a, x, h0, *, seq_chunk=128, chan_tile=LANES,
-                      interpret=True):
+                      interpret=False):
     """a, x: (B, S, D); h0: (B, D) -> (h_all, h_last)."""
     b, s, d = a.shape
     seq_chunk = min(seq_chunk, s)

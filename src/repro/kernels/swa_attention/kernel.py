@@ -74,7 +74,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, window, q_block, kv_block,
                    static_argnames=("window", "q_block", "kv_block",
                                     "interpret"))
 def swa_attention_pallas(q, k, v, *, window, q_block=128, kv_block=128,
-                         interpret=True):
+                         interpret=False):
     """q,k,v: (B, S, H, hd), same H (GQA pre-expanded by ops.py)."""
     b, s, h, hd = q.shape
     q_block = min(q_block, s)

@@ -39,6 +39,7 @@ from ..core.gamma import GammaModel
 from ..core.schedules import Schedule
 from ..core.types import HyperParams
 from ..data.synthetic import ClassificationTask, LMTask
+from .cache import enable_compile_cache
 from ..models.toy import ClassifierGradFn, make_classifier_fns
 
 
@@ -143,6 +144,7 @@ def main(argv=None):
                     help="write a metrics snapshot JSON (staleness/gap/"
                          "drain-k histograms, depth/busy series)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     params0, grad_fn, next_batch, eval_fn = _setup(args)
     sched = None

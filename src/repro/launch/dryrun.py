@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from ..configs import INPUT_SHAPES, get_config, list_configs
 from ..models.api import build_model, cache_spec_for, supports_shape
 from ..roofline.analysis import analyze_compiled, analytic_model_flops
+from .cache import enable_compile_cache
 from .mesh import make_production_mesh
 from .sharding import (batch_specs, cache_pspecs, param_pspecs,
                        to_shardings)
@@ -182,6 +183,7 @@ def main():
     ap.add_argument("--kv-quant", action="store_true",
                     help="int8 KV cache for decode shapes")
     args = ap.parse_args()
+    enable_compile_cache()
 
     archs = list_configs() if args.all or not args.arch else [args.arch]
     shapes = list(INPUT_SHAPES) if args.all or not args.shape \

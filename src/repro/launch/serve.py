@@ -24,6 +24,7 @@ import numpy as np
 from ..configs import get_config
 from ..models.api import build_model
 from ..models.attention import CacheSpec
+from .cache import enable_compile_cache
 from .mesh import make_host_mesh
 from .steps import build_decode_step, build_prefill_step
 
@@ -91,6 +92,7 @@ def main(argv=None):
     ap.add_argument("--devices", type=int, default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

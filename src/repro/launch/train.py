@@ -33,6 +33,7 @@ from ..configs import get_config
 from ..core.schedules import Schedule
 from ..data.synthetic import LMTask
 from ..models.api import build_model
+from .cache import enable_compile_cache
 from .mesh import make_host_mesh
 from .sharding import batch_specs, to_shardings
 from .steps import TrainSettings, build_train_step, init_train_state
@@ -58,6 +59,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -27,16 +27,10 @@ def _shard_mapped(fn, args, arg_axes, out_axes):
     mesh = current_mesh()
     if mesh is None:
         return fn(*args)
-    try:
-        from jax import shard_map
-        kw = {"check_vma": False}
-    except ImportError:      # older jax: experimental home, check_rep arg
-        from jax.experimental.shard_map import shard_map
-        kw = {"check_rep": False}
     in_specs = tuple(clean_pspec(a, *ax) for a, ax in zip(args, arg_axes))
     out_specs = tuple(out_axes)
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, **kw)(*args)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
 
 
 # ---------------------------------------------------------------------------

@@ -8,11 +8,14 @@ Contracts:
     reference — incl. the sent-snapshot slab, delay compensation, and
     per-message schedule scalars — and ONE k-message call equals k
     sequential 1-message calls for mixed/duplicated worker ids;
-  * the master's flat fused pass is bit-identical to the tree fused pass
-    for EVERY kernel-eligible algorithm in the registry, moving lr
-    schedules included (gap-aware to reduction-order tolerance: its
+  * the master's flat fused pass and the tree fused pass both match the
+    op-by-op reference for EVERY kernel-eligible algorithm in the
+    registry, moving lr schedules included — to compile-rounding ulps
+    for the elementwise family (separately compiled programs round
+    differently), to reduction-order tolerance for gap-aware (its
     penalty is a norm over the flat buffer instead of leaf-by-leaf);
-  * the engine's flat execution reproduces the tree engine bit-for-bit;
+  * the engine's flat and tree executions both reproduce the op-by-op
+    engine run;
   * ``eligibility_matrix`` — the documented flat/shard/schedule
     eligibility contract — cannot silently regress.
 """
@@ -65,6 +68,47 @@ def _assert_trees_close(a, b, tol):
     for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y),
                                    rtol=tol, atol=tol)
+
+
+# Two separately compiled XLA programs do not round alike: the CPU
+# backend contracts a*b + c into one FMA in some programs and not in
+# others, so the tree pass, the flat pass and an op-by-op reference may
+# each differ by an ulp or so per f32 operation.  A contract that spans
+# two compilations therefore compares each side with the op-by-op
+# reference, to a few ulps of the reference's largest magnitude:
+CHAIN_ULPS = 8        # one k <= 8 message batch, ~10 f32 ops per element
+ENGINE_ULPS = 64      # 60 engine steps; each step's gradient is taken at
+#                       the previous steps' params, so the ulps compound
+F32_EPS = float(np.finfo(np.float32).eps)
+
+
+def _assert_close_to_ref(got, ref, ulps, tol=0.0):
+    """``got`` matches the op-by-op reference to ``ulps`` f32 ulps of
+    the reference's largest magnitude, or to ``tol`` where a member's
+    own reduction order already sets a wider one."""
+    for x, r in zip(jax.tree.leaves(got), jax.tree.leaves(ref),
+                    strict=True):
+        r = np.asarray(r, np.float32)
+        scale = float(np.max(np.abs(r))) if r.size else 0.0
+        np.testing.assert_allclose(
+            np.asarray(x, np.float32), r,
+            rtol=max(tol, ulps * F32_EPS),
+            atol=max(tol, ulps * F32_EPS * scale))
+
+
+def _eager_reference(name, n, ids_l, grads, nows_l=None, schedule=None):
+    """The plain reference: the algorithm's own receive->send applied
+    message by message, op by op (no jit, so no fusion)."""
+    algo = make_algorithm(name, HP, schedule)
+    views = []
+    with jax.disable_jit():
+        state = algo.init(PARAMS0, n)
+        for j, i in enumerate(ids_l):
+            now = 0.0 if nows_l is None else nows_l[j]
+            state, view = algo.receive_send(state, jnp.int32(i), grads[j],
+                                            jnp.float32(now))
+            views.append(view)
+    return state, views
 
 
 # ---------------------------------------------------------------------------
@@ -304,7 +348,8 @@ def _fam_keys(algo):
 def _check_flat_vs_tree(name, ids_l, schedule=None, k_batch=None,
                         nows_l=None):
     """Drive the SAME message sequence through the tree master's fused
-    pass and the flat master's batched kernel; compare state + views.
+    pass and the flat master's batched kernel; compare each side's state
+    and views with the op-by-op reference (``_eager_reference``).
     ``nows_l`` feeds per-message timestamps (dana-hetero's rate lane)."""
     n = 4
     _, state, m_tree = _masters(name, n, schedule)
@@ -329,18 +374,17 @@ def _check_flat_vs_tree(name, ids_l, schedule=None, k_batch=None,
         v_t.extend(vt)
         v_f.extend(spec.unpack(v) for v in vf)
     tree_f = m_flat._flat_algo.tree_state(s_f)
+    s_r, v_r = _eager_reference(name, n, ids_l, grads, nows_l, schedule)
     tol = _fused_tol(name)
-    # dana-hetero: the STATE stays bit-exact (the weighted mix only
-    # shapes the reply views); its views carry the tolerance
+    # dana-hetero: the STATE is elementwise (the weighted mix only
+    # shapes the reply views); its views carry the reduction tolerance
     state_tol = 0.0 if name == "dana-hetero" else tol
     for key in _fam_keys(algo_f):
-        if state_tol == 0.0:
-            _assert_trees_equal(s_t[key], tree_f[key])
-        else:
-            _assert_trees_close(s_t[key], tree_f[key], state_tol)
-    for a, b in zip(v_t, v_f):
-        (_assert_trees_equal if tol == 0.0 else
-         lambda x, y: _assert_trees_close(x, y, tol))(a, b)
+        for got in (s_t, tree_f):
+            _assert_close_to_ref(got[key], s_r[key], CHAIN_ULPS, state_tol)
+    for a, b, r in zip(v_t, v_f, v_r, strict=True):
+        _assert_close_to_ref(a, r, CHAIN_ULPS, tol)
+        _assert_close_to_ref(b, r, CHAIN_ULPS, tol)
 
 
 @pytest.mark.parametrize("name", ELIGIBLE)
@@ -377,7 +421,8 @@ def test_hetero_flat_matches_tree_batched(k):
 def test_momentum_free_and_lwp_flat_bit_exact(name, k):
     """The newly eligible asgd (gamma = 0 family update) and lwp
     (shared momentum + tau look-ahead, hat mode "self") are elementwise:
-    flat == tree bit-for-bit at every batch size."""
+    flat and tree both match the op-by-op reference to compile-rounding
+    ulps at every batch size."""
     _check_flat_vs_tree(name, [1, 3, 1, 0, 2, 1, 3, 3], k_batch=k)
 
 
@@ -386,8 +431,9 @@ def test_momentum_free_and_lwp_flat_bit_exact(name, k):
 def test_scheduled_flat_matches_tree_fused(name):
     """Moving lr schedule (warm-up ramp + decay milestones inside the
     run): the flat path's per-message lr(t)/lr(t+1) + lazy vscale feed
-    must reproduce the tree path — bit-for-bit for the elementwise
-    family.  This is the lifted constant-lr restriction."""
+    must reproduce the tree path — both match the op-by-op reference to
+    compile-rounding ulps for the elementwise family.  This is the
+    lifted constant-lr restriction."""
     _check_flat_vs_tree(name, [1, 3, 1, 0, 2, 1, 3, 3], schedule=SCHED,
                         k_batch=4)
 
@@ -686,20 +732,21 @@ def test_engine_flat_execution_matches_tree(name, schedule):
         return run_simulation(algo, GRAD_FN, PARAMS0, TASK.batch, cfg)
 
     h_t, h_f = run(False), run(True)
-    # k=1 is bit-exact for everything elementwise; ga-asgd's penalty
-    # reduction order drifts over the 60-step run (allclose only), and
-    # dana-hetero's weighted views feed the next gradients (same drift)
+    with jax.disable_jit():
+        h_r = run(False)                 # the op-by-op reference
+    # ga-asgd's penalty reduction order drifts over the 60-step run, and
+    # dana-hetero's weighted views feed the next gradients (same drift);
+    # the Nadam pair's sqrt/divide fuse differently across lowerings
     tol = {"dana-nadam": 2e-6, "nadam-asgd": 2e-6, "ga-asgd": 5e-4,
            "dana-hetero": 5e-4}.get(name, 0.0)
-    if tol == 0.0:
-        _assert_trees_equal(h_t.final_params, h_f.final_params)
-        assert h_t.gap == h_f.gap
-    else:
-        _assert_trees_close(h_t.final_params, h_f.final_params, tol)
-        np.testing.assert_allclose(h_t.gap, h_f.gap, rtol=1e-3, atol=1e-5)
-    assert h_t.time == h_f.time
-    assert h_t.worker == h_f.worker
-    assert h_t.lag == h_f.lag
+    for h in (h_t, h_f):
+        _assert_close_to_ref(h.final_params, h_r.final_params, ENGINE_ULPS,
+                             tol)
+        np.testing.assert_allclose(h.gap, h_r.gap, rtol=max(tol, 1e-5),
+                                   atol=1e-7)
+        assert h.time == h_r.time
+        assert h.worker == h_r.worker
+        assert h.lag == h_r.lag
 
 
 def test_engine_flat_rejects_ineligible():

@@ -31,7 +31,7 @@ import pytest
 
 from repro.cluster import ClusterConfig, Mailbox, Master, run_cluster
 from repro.cluster.mailbox import GradMsg
-from repro.core import GammaModel, HyperParams, make_algorithm
+from repro.core import FlatSpec, GammaModel, HyperParams, make_algorithm
 from repro.core.metrics import History
 from repro.data.synthetic import ClassificationTask
 from repro.kernels.flat_update import FlatAlgorithm
@@ -368,15 +368,15 @@ def test_cluster_hot_row_pulls_with_dropout():
 # placement: busy_s-driven row rebalancing
 # ---------------------------------------------------------------------------
 def test_rebalance_moves_rows_and_preserves_math(monkeypatch):
-    """Two shards with deliberately skewed ranges (1040 vs 8 rows of a
-    [256, 512, 4] model): the watermark rebalancer must move at least
-    one row range from the overloaded shard, and the final params must
+    """Two shards with deliberately skewed ranges (1272 vs 8 of the 1280
+    rows of a [256, 512, 4] model): the watermark rebalancer must move at
+    least one row range from the overloaded shard, and the final params must
     be bit-identical to the same run with rebalancing off — placement
     changes where rows live, never what they compute.
 
     The busy signal is pinned to rows-held-per-shard: on this CPU the
     per-message cost is dispatch-dominated, so the real wall-clock
-    ``busy_s`` gap between a 1040-row and an 8-row shard is small
+    ``busy_s`` gap between a 1272-row and an 8-row shard is small
     enough that suite-level machine load can flip the threshold — the
     decision input is deterministic here, every layer downstream of it
     (watermark plan cache, rendezvous, slice/merge handoff, moving wire
@@ -389,6 +389,8 @@ def test_rebalance_moves_rows_and_preserves_math(monkeypatch):
                               seed=3)
     init, grad_fn, _ = make_classifier_fns([256, 512, 4])
     params0 = init(jax.random.PRNGKey(0))
+    rows = FlatSpec.from_tree(params0).rows          # 1280: 5 full tiles
+    cut = rows - 8
 
     def run(rebalance):
         algo = make_algorithm("dana-zero", HP)
@@ -396,7 +398,7 @@ def test_rebalance_moves_rows_and_preserves_math(monkeypatch):
             num_workers=4, total_grads=40, eval_every=10,
             mode="deterministic", coalesce=1, exec_model=GammaModel(seed=5),
             shards=2, record_telemetry=False,
-            shard_ranges=((0, 1040), (1040, 1048)),
+            shard_ranges=((0, cut), (cut, rows)),
             rebalance=rebalance, rebalance_threshold=1.05)
         stats = {}
         hist = run_cluster(algo, grad_fn, params0, task.batch, cfg,
@@ -410,7 +412,7 @@ def test_rebalance_moves_rows_and_preserves_math(monkeypatch):
     for wm, donor, recv, n_rows in moves:
         assert donor == 0 and recv == 1 and n_rows % 8 == 0 and n_rows > 0
     r0, r1 = s_rb["shard_ranges"][0]
-    assert (r1 - r0) < 1040                 # shard 0 really shrank
+    assert (r1 - r0) < cut                  # shard 0 really shrank
     for a, b in zip(jax.tree.leaves(p_no), jax.tree.leaves(p_rb)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 

@@ -154,6 +154,21 @@ def test_process_backend_rejects_hot_rows():
         run_cluster(algo, GRAD_FN, PARAMS0, TASK.batch, cfg)
 
 
+def test_process_backend_refused_on_tpu(monkeypatch):
+    """Only one process may hold a TPU chip: on a TPU backend the run is
+    refused before any child is spawned."""
+    import multiprocessing
+
+    def no_spawn(*a, **k):
+        raise AssertionError("a child process was started")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(multiprocessing, "get_context", no_spawn)
+    algo = make_algorithm("dana-zero", HP)
+    with pytest.raises(ValueError, match="one process may hold a TPU"):
+        run_cluster(algo, GRAD_FN, PARAMS0, TASK.batch, _cfg("process"))
+
+
 # ---------------------------------------------------------------------------
 # regression: master shutdown hang (unbounded join)
 # ---------------------------------------------------------------------------
