@@ -25,7 +25,6 @@ from __future__ import annotations
 import collections
 import dataclasses
 import threading
-import time
 from typing import Any
 
 from ..obs import trace
@@ -53,19 +52,24 @@ class GradMsg:
     declares hot: the master may serve the view over just those rows
     (``Reply.rows`` echoes the range it honored; sent-snapshot masters
     fall back to the full view and leave it None).
+
+    ``(worker_id, seq)`` identifies the gradient: ``seq`` is the
+    worker's own gradient number, carried by every trace span of that
+    gradient on both sides of the mailbox.
     """
 
     __slots__ = ("worker_id", "grad", "view", "view_step", "t_send",
-                 "rows", "_event", "_reply")
+                 "rows", "seq", "_event", "_reply")
 
     def __init__(self, worker_id: int, grad: Any, view: Any,
-                 view_step: int, t_send: float, rows=None):
+                 view_step: int, t_send: float, rows=None, seq: int = -1):
         self.worker_id = worker_id
         self.grad = grad
         self.view = view              # params the gradient was computed on
         self.view_step = view_step    # master step the view was issued at
         self.t_send = t_send          # virtual (det/paced) or wall time
         self.rows = rows              # hot-row range for pull-only requests
+        self.seq = seq                # the worker's gradient number
         self._event = threading.Event()
         self._reply: Reply | None = None
 
@@ -310,7 +314,6 @@ class Mailbox:
     def put(self, msg: GradMsg, stop: threading.Event) -> bool:
         """Enqueue; blocks while full.  Returns False if the cluster shut
         down before the message could be enqueued."""
-        t0 = time.perf_counter() if trace.enabled else 0.0
         with self._cond:
             while self._capacity and len(self._q) >= self._capacity:
                 if stop.is_set():
@@ -321,9 +324,6 @@ class Mailbox:
             self._q.append(msg)
             self._depth = len(self._q)
             self._cond.notify_all()
-        if trace.enabled:
-            trace.complete("put", "mailbox", t0,
-                           time.perf_counter() - t0, worker=msg.worker_id)
         return True
 
     def drain(self, max_k: int, stop: threading.Event,
@@ -337,10 +337,16 @@ class Mailbox:
         master's fused receive compiles O(log k) variants instead of one
         per batch size (at steady state the queue is deep and the batch is
         exactly ``max_k`` anyway)."""
-        t0 = time.perf_counter() if trace.enabled else 0.0
+        # the span is mostly WAIT time: in Perfetto, long drain spans
+        # against short apply spans = an under-fed (idle) server
+        tr = trace.enabled
+        if tr:
+            trace.begin("mailbox.drain", "mailbox")
         with self._cond:
             while not self._q:
                 if stop.is_set():
+                    if tr:
+                        trace.end(k=0)
                     return []
                 self._cond.wait(timeout=timeout)
             k = min(max_k, len(self._q))
@@ -349,11 +355,8 @@ class Mailbox:
             out = [self._q.popleft() for _ in range(k)]
             self._depth = len(self._q)
             self._cond.notify_all()
-        if trace.enabled:
-            # the span is mostly WAIT time: in Perfetto, long drain spans
-            # against short apply spans = an under-fed (idle) server
-            trace.complete("drain", "mailbox", t0,
-                           time.perf_counter() - t0, k=k)
+        if tr:
+            trace.end(k=k)
         return out
 
     def drain_nowait(self) -> list[GradMsg]:

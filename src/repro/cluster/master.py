@@ -49,6 +49,7 @@ loops mirror this one (``ClusterConfig(shards=S)``).
 """
 from __future__ import annotations
 
+import collections
 import threading
 import time
 from typing import Any, Callable
@@ -87,9 +88,18 @@ def run_serve_loop(server):
     (a ``serve_instruments`` bundle or None) gets the drained-batch-size
     histogram, pull/overflow counters and the memory-tier traffic
     counters (``slab_info = (n_slab_workers, rows_per_sender)`` on flat
-    servers, None on the tree path), and when tracing is enabled the
-    already-measured ``busy_s`` interval doubles as the apply span under
-    the ``server.obs_cat`` category ("master" or "shard").
+    servers, None on the tree path).
+
+    ``server.busy_s`` accumulates the serve thread's host time inside
+    ``_apply``: stacking, id/time transfers, the dispatch of the receive
+    and the replies.  The receive itself runs on the device
+    asynchronously, so this is dispatch time, not device time.  When
+    tracing is enabled each receive is a ``<obs_cat>.apply`` span
+    (``master.apply`` or ``shard.apply``) with its size ``k``, its first
+    apply ``step``, the first gradient's ``(worker, seq)`` and, where
+    the server counts them, ``in_flight``: the earlier receives the
+    device had not finished when this one was dispatched.  Pull replies
+    are ``<obs_cat>.pull`` spans.
 
     Chunks additionally never straddle an eval boundary
     (``server.eval_boundary``, 0 when no eval is configured): evals run
@@ -126,6 +136,11 @@ def run_serve_loop(server):
                 chunk, work = work[:k], work[k:]
                 server.coalesce_counts[k] = \
                     server.coalesce_counts.get(k, 0) + 1
+                tr = trace.enabled
+                if tr:
+                    trace.begin(server.obs_cat + ".apply", server.obs_cat,
+                                k=k, step=server.applied + 1,
+                                worker=chunk[0].worker_id, seq=chunk[0].seq)
                 t_in = time.perf_counter()
                 server._apply(chunk)
                 dt = time.perf_counter() - t_in
@@ -143,21 +158,24 @@ def run_serve_loop(server):
                         u = min(len({m.worker_id for m in chunk}), n_slab)
                         mx.slab_rows_streamed.add(u * rows2)
                         mx.slab_rows_total.add(n_slab * rows2)
-                if trace.enabled:
-                    # reuse the busy_s interval: the apply span costs the
-                    # traced path zero extra clock reads
-                    trace.complete("apply", server.obs_cat, t_in, dt, k=k)
+                if tr:
+                    in_flight = getattr(server, "in_flight", None)
+                    if in_flight is None:
+                        trace.end()
+                    else:
+                        trace.end(in_flight=in_flight)
             if pulls and mx is not None:
                 mx.pulls.add(len(pulls))
             for m in pulls:
-                t_p = time.perf_counter() if trace.enabled else 0.0
+                tr = trace.enabled
+                if tr:
+                    trace.begin(server.obs_cat + ".pull", server.obs_cat,
+                                worker=m.worker_id)
                 served_rows = server._pull_reply(m)
                 if mx is not None and served_rows:
                     mx.pull_rows.add(served_rows)
-                if trace.enabled:
-                    trace.complete("pull", server.obs_cat, t_p,
-                                   time.perf_counter() - t_p,
-                                   worker=m.worker_id)
+                if tr:
+                    trace.end()
             if overflow and mx is not None:
                 mx.overflow.add(len(overflow))
             for m in overflow:
@@ -177,8 +195,11 @@ def run_serve_loop(server):
 def fused_flat_program(fa, k: int, telemetry: bool):
     """The master's fused receive for a k-message drain of
     ``FlatAlgorithm`` ``fa``: ``jit(flat, ids, nows, g_flat, views) ->
-    (flat, views, gaps, gnorms[, staleness])``, state donated.  ONE
-    batched flat kernel for the whole drain.
+    (flat, views, gaps, gnorms[, staleness], done)``, state donated.  ONE
+    batched flat kernel for the whole drain, under the name scope
+    ``receive``.  ``done`` is a scalar read from the updated state: it
+    holds no large buffer and is never donated, so ``done.is_ready()``
+    tells, without a sync, whether the device has finished this receive.
 
     Everything on the wire is already flat, and the batch arrives
     STACKED: ``g_flat`` (and ``views`` under telemetry) is one
@@ -190,7 +211,7 @@ def fused_flat_program(fa, k: int, telemetry: bool):
     """
     inv_sqrt_p = 1.0 / float(np.sqrt(fa.spec.n_elems))
 
-    def fused(flat, ids, nows, g_flat, views):
+    def receive(flat, ids, nows, g_flat, views):
         # per-message sent-snapshot staleness comes from the scalar
         # lane, read BEFORE apply_batch consumes the donated state
         # (None for snapshot-free members)
@@ -199,12 +220,17 @@ def fused_flat_program(fa, k: int, telemetry: bool):
         flat, hats, pres = fa.apply_batch(flat, ids, g_flat, nows,
                                           telemetry=telemetry)
         out_views = tuple(hats[j] for j in range(k))
+        done = flat["theta"][0, 0]
         if telemetry:
             d = pres - views             # zero in the padding region
             gaps = jnp.sqrt(jnp.sum(d * d, axis=(1, 2))) * inv_sqrt_p
             gnorms = jnp.sqrt(jnp.sum(g_flat * g_flat, axis=(1, 2)))
-            return flat, out_views, gaps, gnorms, stals
-        return flat, out_views, None, None
+            return flat, out_views, gaps, gnorms, stals, done
+        return flat, out_views, None, None, done
+
+    def fused(flat, ids, nows, g_flat, views):
+        with jax.named_scope("receive"):
+            return receive(flat, ids, nows, g_flat, views)
 
     # the flat state is donated: the batched kernel aliases its state
     # inputs to its outputs (input_output_aliases), so the update
@@ -307,10 +333,15 @@ class Master:
         # applied (compile + ramp-up excluded from steady throughput)
         self._steady_mark = max(1, total_grads // 5)
         self.steady_t: float | None = None
-        # master-thread occupancy applying gradients (drain waits excluded):
-        # applied/busy_s is the master's live service rate — the number
-        # coalescing is meant to raise
+        # the master thread's host time in _apply (drain waits excluded):
+        # stacking, transfers, the receive's dispatch and the replies.
+        # The receive runs asynchronously, so this is dispatch time, not
+        # device time
         self.busy_s = 0.0
+        # traced runs only: the ``done`` scalars of the last receives,
+        # and how many of them were unfinished at the latest dispatch
+        self._done: collections.deque = collections.deque(maxlen=8)
+        self.in_flight: int | None = None
 
     # -- worker-visible state -------------------------------------------
     @property
@@ -447,22 +478,33 @@ class Master:
         fn, st = self._fused_for(k, telemetry)
         ids = jnp.asarray([m.worker_id for m in work], jnp.int32)
         nows = jnp.asarray([m.t_send for m in work], jnp.float32)
+        tr = trace.enabled
         if self.state_is_flat:
             # stacked wire format: ONE (k, R, 128) buffer per batch (one
             # concatenate dispatch here; the process backend stages into
             # a preallocated host buffer and ships a single transfer)
+            if tr:
+                trace.begin("master.stack", "master", k=k,
+                            worker=work[0].worker_id, seq=work[0].seq)
             grads = jnp.stack([m.grad for m in work])
             views = (jnp.stack([m.view for m in work]) if telemetry
                      else None)
+            if tr:
+                trace.end()
+                self.in_flight = sum(not d.is_ready() for d in self._done)
         else:
             grads = tuple(m.grad for m in work)
             views = tuple(m.view for m in work) if telemetry else None
         t0 = self._step
+        out = fn(st, ids, nows, grads, views)
+        if self.state_is_flat:
+            *out, done = out
+            if tr:
+                self._done.append(done)
         if telemetry:
-            st, out_views, gaps, gnorms, stals = fn(st, ids, nows, grads,
-                                                    views)
+            st, out_views, gaps, gnorms, stals = out
         else:
-            st, out_views, _, _ = fn(st, ids, nows, grads, views)
+            st, out_views, _, _ = out
             gaps = gnorms = stals = None
         if self.state_is_flat:
             self._flat_state = st

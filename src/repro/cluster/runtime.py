@@ -23,7 +23,7 @@ from ..core.gamma import GammaModel
 from ..core.metrics import History
 from ..core.types import Pytree
 from ..kernels.flat_update import kernel_eligible
-from ..obs import trace
+from ..obs import compiles, trace
 from ..obs.metrics import (MetricsRegistry, SnapshotPublisher,
                            history_observer, serve_instruments)
 from .clock import VirtualClock
@@ -85,9 +85,15 @@ class ClusterConfig:
 def flat_grad_program(spec, grad_fn, donate=()):
     """The worker's program on the flat wire: unpack the (R, 128) view,
     take the gradient, ``pack_fused`` it into the (R, 128) wire — one
-    jit, ``jit(view, batch) -> wire``; ``donate=(0,)`` donates the view."""
-    return jax.jit(lambda fv, batch: spec.pack_fused(
-        grad_fn(spec.unpack(fv), batch)), donate_argnums=donate)
+    jit, ``jit(view, batch) -> wire``, its operations under the name
+    scope ``worker_grad``; ``donate=(0,)`` donates the view."""
+    def worker_grad(fv, batch):
+        with jax.named_scope("worker_grad"):
+            return spec.pack_fused(grad_fn(spec.unpack(fv), batch))
+
+    # a lambda, so the program keeps its name (``jit__lambda``)
+    return jax.jit(lambda fv, batch: worker_grad(fv, batch),
+                   donate_argnums=donate)
 
 
 def run_cluster(
@@ -114,6 +120,17 @@ def run_cluster(
     busy time off the hot path (its series lands in
     ``stats_out["obs_series"]``).  ``metrics=None`` (the default) leaves
     the hot path exactly as before — the instruments are never touched.
+
+    Tracing (``repro.obs.trace``): when ``jax.profiler`` is recording as
+    the call starts and the tracer is off, the call turns the tracer on
+    for its own duration (no ``SnapshotPublisher``: that needs
+    ``metrics`` or an explicit ``trace.enable()``), so its spans land on
+    the profile's host plane.  Whenever the tracer was on, the call's
+    spans are returned in ``stats_out["spans"]`` (``trace.events``
+    format).  ``stats_out["compile"]`` is the process's running compile
+    total (``repro.obs.compiles``) plus ``in_call``: the
+    ``(fun_name, seconds, applied_at)`` of every compile event during
+    the call, ``applied_at`` being the gradients applied by then.
     """
     if cfg.mode not in MODES:
         raise ValueError(f"mode must be one of {MODES}, got {cfg.mode!r}")
@@ -183,6 +200,37 @@ def run_cluster(
         raise ValueError("shards > 1 requires the flat kernel master "
                          "(use_kernel must not be False)")
 
+    # the publisher samples every 5 ms, so only metrics or an explicit
+    # trace.enable() start it, never a profile alone
+    publish = metrics is not None or trace.enabled
+    own_trace = not trace.enabled and trace.profiler_recording()
+    if own_trace:
+        trace.enable()
+    traced = trace.enabled
+    t_call = time.perf_counter()
+    watch = compiles.watch()
+    try:
+        history = _run_threads(algo, grad_fn, params0, next_batch, cfg,
+                               eval_fn, stats_out, metrics, use_kernel,
+                               publish, watch)
+    finally:
+        watch.close()
+        if own_trace:
+            trace.disable()
+    if stats_out is not None:
+        stats_out["compile"] = dict(compiles.totals(),
+                                    in_call=watch.records)
+        if traced:
+            stats_out["spans"] = trace.events(since=t_call)
+    return history
+
+
+def _run_threads(algo, grad_fn, params0, next_batch, cfg, eval_fn,
+                 stats_out, metrics, use_kernel, publish, watch):
+    """``run_cluster``'s threaded backend, once the call is validated."""
+    n = cfg.num_workers
+    deterministic = cfg.mode == "deterministic"
+    sharded = cfg.shards > 1
     injector = (FaultInjector(cfg.faults, n, cfg.exec_model.batch_size)
                 if cfg.faults is not None else None)
     stop = threading.Event()
@@ -233,6 +281,7 @@ def run_cluster(
             use_kernel=use_kernel, record_telemetry=cfg.record_telemetry,
             eval_fn=eval_fn, eval_every=cfg.eval_every, injector=injector,
             time_fn=time_fn, pipeline_depth=cfg.pipeline_depth)
+    watch.progress = lambda: master.applied
     # the master packed (or adopted) the algorithm state; dropping this
     # reference frees the pytree copy, which at real widths is
     # (N + 2) parameter copies of device memory
@@ -248,7 +297,7 @@ def run_cluster(
                 srv.metrics = serve_mx       # shared: per-thread cells
         else:
             master.metrics = serve_mx
-    if metrics is not None or trace.enabled:
+    if publish:
         # gauge sources are lock-free reads (Mailbox.depth contract),
         # sampled by a background thread — never by cluster threads
         if sharded:

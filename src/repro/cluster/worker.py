@@ -45,7 +45,6 @@ storage for the wire buffer.
 from __future__ import annotations
 
 import threading
-import time
 from collections import deque
 from typing import Any, Callable
 
@@ -148,27 +147,33 @@ class Worker(threading.Thread):
                 self.clock.stop()
 
     # -- pipelined RPC halves (pipeline_depth > 0) -----------------------
-    def _post(self, grad, t_send: float) -> GradMsg | None:
+    def _post(self, grad, t_send: float, seq: int) -> GradMsg | None:
         """Enqueue one push without waiting for its reply (the pull-ahead
         half-RPC); returns the in-flight message, or None on shutdown."""
         msg = GradMsg(self.wid, grad,
                       self._view if (self.telemetry and grad is not None)
                       else None,
-                      self._view_step, t_send)
+                      self._view_step, t_send, seq=seq)
         if not self.mailbox.put(msg, self.stop):
             return None
         if trace.enabled:
-            trace.instant("rpc_post", "worker", worker=self.wid)
+            trace.instant("worker.rpc_post", "worker", worker=self.wid,
+                          seq=seq)
         return msg
 
     def _await(self, msg: GradMsg) -> bool:
         """Settle one in-flight push: wait for its reply and adopt the
         fresher view."""
-        t0 = time.perf_counter() if trace.enabled else 0.0
+        tr = trace.enabled
+        if tr:
+            trace.begin("worker.rpc_await", "worker", worker=self.wid,
+                        seq=msg.seq)
         reply = msg.wait_reply(self.rpc_timeout)
-        if trace.enabled:
-            trace.complete("rpc_await", "worker", t0,
-                           time.perf_counter() - t0)
+        if tr:
+            if reply is None:
+                trace.end()
+            else:
+                trace.end(step=reply.step)
         if reply is None:
             return False
         self._view, self._view_step = reply.view, reply.step
@@ -183,21 +188,31 @@ class Worker(threading.Thread):
         return ok
 
     # -- one RPC ---------------------------------------------------------
-    def _push(self, grad, t_send: float) -> bool:
+    def _push(self, grad, t_send: float, seq: int = -1) -> bool:
+        """One fused push-pull RPC; ``seq`` is the gradient's number
+        (-1 for a pull-only request)."""
         msg = GradMsg(self.wid, grad,
                       self._view if (self.telemetry and grad is not None)
                       else None,
                       self._view_step, t_send,
-                      rows=self.hot_rows if grad is None else None)
-        t0 = time.perf_counter() if trace.enabled else 0.0
+                      rows=self.hot_rows if grad is None else None,
+                      seq=seq)
+        # the fused push-pull round trip: enqueue + queueing delay +
+        # master service time, as seen from this worker, up to the
+        # reply in hand (its step is the gradient's apply step)
+        tr = trace.enabled
+        if tr:
+            trace.begin("worker.rpc", "worker", worker=self.wid, seq=seq)
         if not self.mailbox.put(msg, self.stop):
+            if tr:
+                trace.end()
             return False
         reply = msg.wait_reply(self.rpc_timeout)
-        if trace.enabled:
-            # the fused push-pull round trip: enqueue + queueing delay +
-            # master service time, as seen from this worker
-            trace.complete("rpc", "worker", t0, time.perf_counter() - t0,
-                           pull_only=grad is None)
+        if tr:
+            if reply is None:
+                trace.end()
+            else:
+                trace.end(step=reply.step)
         if reply is None:
             return False
         if reply.rows is not None:
@@ -211,6 +226,23 @@ class Worker(threading.Thread):
             self.grads_sent += 1
         return True
 
+    def _grad(self, seq: int):
+        """Gradient ``seq`` of this worker: the call into the feed, then
+        the call that dispatches the backward->wire program (batch
+        transfer and any blocking by the runtime included)."""
+        tr = trace.enabled
+        if tr:
+            trace.begin("worker.next_batch", "worker", worker=self.wid,
+                        seq=seq)
+        batch = self.next_batch(self.wid, seq)
+        if tr:
+            trace.end()
+            trace.begin("worker.grad", "worker", worker=self.wid, seq=seq)
+        grad = self.grad_jit(self._view, batch)
+        if tr:
+            trace.end()
+        return grad
+
     # -- deterministic mode ---------------------------------------------
     def _run_deterministic(self):
         counter = 0
@@ -222,14 +254,9 @@ class Worker(threading.Thread):
             try:
                 if (not self.stop.is_set()
                         and self.master.applied < self.master.total):
-                    batch = self.next_batch(self.wid, counter)
+                    grad = self._grad(counter)
+                    ok = self._push(grad, t, counter)
                     counter += 1
-                    tg = time.perf_counter() if trace.enabled else 0.0
-                    grad = self.grad_jit(self._view, batch)
-                    if trace.enabled:
-                        trace.complete("grad", "worker", tg,
-                                       time.perf_counter() - tg)
-                    ok = self._push(grad, t)
             finally:
                 if ok:
                     stall = (self.injector.stall(self.wid)
@@ -269,8 +296,8 @@ class Worker(threading.Thread):
                                                    self.master.step)
                 if back is not None:
                     if trace.enabled:
-                        trace.instant("dropout", "faults", worker=self.wid,
-                                      back_step=back)
+                        trace.instant("faults.dropout", "faults",
+                                      worker=self.wid, back_step=back)
                     # an offline worker abandons its pipeline first: the
                     # in-flight pushes settle, then the stale view is
                     # discarded by the rejoin pull
@@ -278,7 +305,8 @@ class Worker(threading.Thread):
                     if not self._await_rejoin(back):
                         return
                     if trace.enabled:
-                        trace.instant("rejoin", "faults", worker=self.wid)
+                        trace.instant("faults.rejoin", "faults",
+                                      worker=self.wid)
                     # rejoin: stale view discarded, pull-only request
                     if not self._push(None, self.now_fn()):
                         return
@@ -291,20 +319,16 @@ class Worker(threading.Thread):
             if self.gate is not None and not self.gate.acquire(self.wid):
                 return
             try:
-                batch = self.next_batch(self.wid, counter)
+                seq = counter
                 counter += 1
-                tg = time.perf_counter() if trace.enabled else 0.0
-                grad = self.grad_jit(self._view, batch)
-                if trace.enabled:
-                    trace.complete("grad", "worker", tg,
-                                   time.perf_counter() - tg)
+                grad = self._grad(seq)
                 if self.pipeline_depth == 0:
-                    ok = self._push(grad, self.now_fn())
+                    ok = self._push(grad, self.now_fn(), seq)
                 else:
                     # pull-ahead: post now, settle the OLDEST in-flight
                     # push only once more than `depth` are outstanding —
                     # the RPC round trip hides behind the next gradient
-                    msg = self._post(grad, self.now_fn())
+                    msg = self._post(grad, self.now_fn(), seq)
                     ok = msg is not None
                     if ok:
                         self._pending.append(msg)

@@ -1,4 +1,5 @@
-"""Lock-free per-thread span tracer with Chrome-trace/Perfetto export.
+"""Lock-free per-thread span tracer with two sinks: an in-memory ring
+exported as Chrome-trace/Perfetto JSON, and the JAX profiler.
 
 The cluster's hot path processes a message in ~13 us, so the tracer's
 contract is asymmetric:
@@ -14,23 +15,35 @@ contract is asymmetric:
   Rings are bounded and drop-oldest — a long run keeps the trace's tail,
   the export records how much was dropped.
 
+The two sinks and their clocks:
+
+* **ring** — every event, stamped with ``time.perf_counter`` seconds
+  relative to the ``enable()`` epoch and exported as microseconds
+  (``export``, ``events``).  Thread names (the runtime names its
+  threads ``ps-master`` / ``ps-shard-N`` / ``ps-worker-N``) become
+  Perfetto track names via ``thread_name`` metadata events.
+* **profiler** — while ``jax.profiler`` is recording, each
+  ``begin``/``end`` span (and each instant) is also a TraceMe
+  (``jax.profiler.TraceAnnotation``) on the calling thread, carrying the
+  same name and args.  It lands on the profile's host plane, on the
+  profiler's own clock, beside the device operations of the same
+  ``.xplane.pb``.  A TraceMe cannot be back-dated, so hot-path spans are
+  begin/end pairs opened at the real start; ``complete()`` (an interval
+  measured after the fact) and counters reach the ring only.
+
+Span names are ``<cat>.<name>`` (``worker.grad``, ``master.apply``,
+``mailbox.drain``); ``cat`` is kept as the event's category.
+
 Event model (a subset of the Chrome trace-event format, so an exported
 file opens directly in ``ui.perfetto.dev`` or ``chrome://tracing``):
 
 * **complete spans** (``ph="X"``) — begin/end pairs via ``begin()`` /
-  ``end()`` (per-thread stack) or one ``complete()`` call when the
-  caller already measured the interval (the serve loop reuses its
-  ``busy_s`` timing, paying zero extra clock reads);
+  ``end()`` (per-thread stack; args given at either end) or one
+  ``complete()`` call when the caller already measured the interval;
 * **instant events** (``ph="i"``) — point markers (fault injections);
 * **counters** (``ph="C"``) — sampled value tracks (mailbox depth,
   per-shard busy time), emitted by the off-hot-path snapshot publisher
   (``repro.obs.metrics.SnapshotPublisher``).
-
-Timestamps are ``time.perf_counter`` seconds relative to the
-``enable()`` epoch, exported as microseconds.  Thread names (the
-runtime names its threads ``ps-master`` / ``ps-shard-N`` /
-``ps-worker-N``) become Perfetto track names via ``thread_name``
-metadata events.
 """
 from __future__ import annotations
 
@@ -39,6 +52,8 @@ import json
 import os
 import threading
 import time
+
+from jax.profiler import TraceAnnotation as _TraceMe
 
 # Module-level no-op guard.  Call sites MUST read this through the
 # module (``trace.enabled``), never ``from ... import enabled`` (which
@@ -114,35 +129,56 @@ def disable():
     enabled = False
 
 
+def profiler_recording() -> bool:
+    """Whether ``jax.profiler`` is recording (spans then reach it too)."""
+    return _TraceMe.is_enabled()
+
+
 # -- recording --------------------------------------------------------------
 # Events are tuples: (ph, name, cat, t0_seconds, dur_seconds|None, args|None)
 
-def begin(name: str, cat: str):
-    """Open a span on this thread's stack (close with ``end()``)."""
-    _ring().stack.append((name, cat, time.perf_counter()))
+def begin(name: str, cat: str, **args):
+    """Open a span on this thread's stack (close with ``end()``); while
+    the profiler records, it is also a TraceMe opened now."""
+    sink = None
+    if _TraceMe.is_enabled():
+        sink = _TraceMe(name, **args)
+        sink.__enter__()
+    _ring().stack.append((name, cat, time.perf_counter(), args, sink))
 
 
 def end(**args):
-    """Close the innermost ``begin()`` span."""
+    """Close the innermost ``begin()`` span; ``args`` join those given
+    at ``begin`` (values known only at the end, such as a reply's step)."""
     t1 = time.perf_counter()
     r = _ring()
     if not r.stack:
         return
-    name, cat, t0 = r.stack.pop()
+    name, cat, t0, first, sink = r.stack.pop()
+    if sink is not None:
+        if args:
+            sink.set_metadata(**args)
+        sink.__exit__(None, None, None)
+    if first:
+        args = {**first, **args}
     r.push(("X", name, cat, t0, t1 - t0, args or None))
 
 
 def complete(name: str, cat: str, t0: float, dur: float, **args):
-    """Record an already-measured interval (perf_counter seconds)."""
+    """Record an already-measured interval (perf_counter seconds); ring
+    only, since a profiler span cannot start in the past."""
     _ring().push(("X", name, cat, t0, max(dur, 0.0), args or None))
 
 
 def instant(name: str, cat: str, **args):
+    if _TraceMe.is_enabled():
+        with _TraceMe(name, **args):
+            pass
     _ring().push(("i", name, cat, time.perf_counter(), None, args or None))
 
 
 def counter(track: str, value: float):
-    """One sample on a Perfetto counter track."""
+    """One sample on a Perfetto counter track (ring only)."""
     _ring().push(("C", track, None, time.perf_counter(), None,
                   {"value": float(value)}))
 
@@ -162,22 +198,22 @@ def span(name: str, cat: str, **args):
 
 
 # -- export -----------------------------------------------------------------
-def export(path: str | None = None) -> dict:
-    """Snapshot all rings into one Chrome-trace JSON object.
+def events(since: float | None = None) -> list[dict]:
+    """Every ring's events in the exporter's format, ordered by start:
+    a ``thread_name`` metadata event per ring, then each event, keeping
+    only those that start at or after ``since`` (``perf_counter``
+    seconds, the ring's clock) when it is given.
 
     Safe to call while threads are still tracing (a live run's partial
     trace) — the snapshot is per-ring consistent up to a possible torn
-    tail slot.  When ``path`` is given the object is also written there.
-    """
+    tail slot."""
     pid = os.getpid()
     with _reg_lock:
         rings = list(_rings)
-    events: list[dict] = []
-    dropped = 0
+    out: list[dict] = []
     for r in rings:
-        events.append({"ph": "M", "name": "thread_name", "pid": pid,
-                       "tid": r.tid, "args": {"name": r.name}})
-        dropped += r.dropped
+        out.append({"ph": "M", "name": "thread_name", "pid": pid,
+                    "tid": r.tid, "args": {"name": r.name}})
         cap = len(r.events)
         idx = r.idx                       # snapshot the write index
         for j in range(max(0, idx - cap), idx):
@@ -185,6 +221,8 @@ def export(path: str | None = None) -> dict:
             if ev is None:
                 continue
             ph, name, cat, t0, dur, args = ev
+            if since is not None and t0 < since:
+                continue
             rec = {"ph": ph, "name": name, "pid": pid, "tid": r.tid,
                    "ts": (t0 - _epoch) * 1e6}
             if cat is not None:
@@ -195,10 +233,19 @@ def export(path: str | None = None) -> dict:
                 rec["s"] = "t"            # thread-scoped instant
             if args:
                 rec["args"] = args
-            events.append(rec)
-    events.sort(key=lambda e: e.get("ts", -1.0))
+            out.append(rec)
+    out.sort(key=lambda e: e.get("ts", -1.0))
+    return out
+
+
+def export(path: str | None = None) -> dict:
+    """Snapshot all rings into one Chrome-trace JSON object (``events()``
+    plus how many events the rings dropped).  When ``path`` is given the
+    object is also written there."""
+    with _reg_lock:
+        dropped = sum(r.dropped for r in _rings)
     obj = {
-        "traceEvents": events,
+        "traceEvents": events(),
         "displayTimeUnit": "ms",
         "otherData": {"dropped_events": dropped,
                       "clock": "perf_counter_us_since_enable"},

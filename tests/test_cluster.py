@@ -185,7 +185,7 @@ def test_kernel_routing_matches_algorithm_path():
                                                   None)
     s_k, v_k, _, _ = m_legacy._get_fused(k, False)(state, ids, nows, grads,
                                                    None)
-    s_f, v_f, _, _ = m_flat._get_fused_flat(k, False)(
+    s_f, v_f, _, _, _ = m_flat._get_fused_flat(k, False)(
         m_flat._flat_state, ids, nows,
         jnp.stack([spec.pack(g) for g in grads]), None)  # stacked wire
     v_f = tuple(spec.unpack(v) for v in v_f)

@@ -368,7 +368,7 @@ def _check_flat_vs_tree(name, ids_l, schedule=None, k_batch=None,
         chunk = grads[off:off + k]
         s_t, vt, _, _ = m_tree._get_fused(k, False)(s_t, ids, nows,
                                                     chunk, None)
-        s_f, vf, _, _ = m_flat._get_fused_flat(k, False)(
+        s_f, vf, _, _, _ = m_flat._get_fused_flat(k, False)(
             s_f, ids, nows, jnp.stack([spec.pack(g) for g in chunk]),
             None)
         v_t.extend(vt)
@@ -452,7 +452,7 @@ def test_flat_fused_telemetry_matches_tree():
     spec = m_flat._flat_algo.spec
     _, _, gaps_t, gn_t, _ = m_tree._get_fused(k, True)(state, ids, nows,
                                                        grads, views)
-    _, _, gaps_f, gn_f, _ = m_flat._get_fused_flat(k, True)(
+    _, _, gaps_f, gn_f, _, _ = m_flat._get_fused_flat(k, True)(
         m_flat._flat_state, ids, nows,
         jnp.stack([spec.pack(g) for g in grads]),
         jnp.stack([spec.pack(v) for v in views]))
@@ -688,7 +688,7 @@ def test_flat_fused_donates_and_aliases_buffers():
     ids = jnp.asarray([0, 1, 2, 3], jnp.int32)
     nows = jnp.zeros((4,), jnp.float32)
     grads = jnp.stack([spec.pack(g) for g in _grads(4, seed=31)])
-    out_state, _, _, _ = fn(st, ids, nows, grads, None)
+    out_state, _, _, _, _ = fn(st, ids, nows, grads, None)
     assert out_state["theta"].unsafe_buffer_pointer() == ptr_theta
     assert out_state["v"].unsafe_buffer_pointer() == ptr_v
     assert st["theta"].is_deleted()
@@ -703,7 +703,7 @@ def test_pull_views_survive_donation():
     before = np.asarray(view).copy()
     fn = m._get_fused_flat(1, False)
     spec = m._flat_algo.spec
-    m._flat_state, _, _, _ = fn(
+    m._flat_state, _, _, _, _ = fn(
         m._flat_state, jnp.asarray([0], jnp.int32),
         jnp.zeros((1,), jnp.float32),
         spec.pack(_grads(1, seed=5)[0])[None], None)

@@ -16,6 +16,7 @@ import threading
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -396,3 +397,191 @@ def test_metrics_registry_params_bit_identical():
     h_metered = _run_cluster("dc-asgd", use_kernel=True,
                              metrics=MetricsRegistry())
     _assert_params_equal(h_plain.final_params, h_metered.final_params)
+
+
+# ---------------------------------------------------------------------------
+# the profiler sink: a profile recording puts the cluster's spans on its
+# host plane and in stats_out["spans"]
+# ---------------------------------------------------------------------------
+HOT_SPANS = ("worker.next_batch", "worker.grad", "worker.rpc",
+             "mailbox.drain", "master.stack", "master.apply")
+
+
+def _pinned_run(stats, grad_fn=GRAD_FN, grads=24):
+    """A free-mode flat-kernel run whose message order is pinned, so its
+    parameters are reproducible."""
+    algo = make_algorithm("dana-zero", HP)
+    cfg = ClusterConfig(num_workers=2, total_grads=grads, mode="free",
+                        pin_schedule=True, record_telemetry=False,
+                        use_kernel=True, exec_model=GammaModel(seed=5))
+    return run_cluster(algo, grad_fn, PARAMS0, TASK.batch, cfg,
+                       stats_out=stats)
+
+
+def _host_plane_spans(directory):
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(os.path.join(str(directory), "**",
+                                         "*.xplane.pb"), recursive=True))[-1]
+    names = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                names += [ev.name for ev in line.events
+                          if ev.name.startswith(HOT_SPANS)]
+    return names
+
+
+@pytest.fixture
+def no_publisher(monkeypatch):
+    """Fails the test if run_cluster starts a SnapshotPublisher."""
+    import repro.cluster.runtime as runtime
+
+    started = []
+
+    class Refused(SnapshotPublisher):
+        def start(self):
+            started.append(self)
+            super().start()
+
+    monkeypatch.setattr(runtime, "SnapshotPublisher", Refused)
+    return started
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    """One pinned run recorded by jax.profiler, and the same run without
+    it: (stats, host-plane span names, params), (stats, params)."""
+    d = tmp_path_factory.mktemp("xplane")
+    stats_p, stats_off = {}, {}
+    h_off = _pinned_run(stats_off)
+    jax.profiler.start_trace(str(d))
+    try:
+        h_on = _pinned_run(stats_p)
+    finally:
+        jax.profiler.stop_trace()
+    assert not trace.enabled          # the call turned its own ring off
+    return ((stats_p, _host_plane_spans(d), h_on.final_params),
+            (stats_off, h_off.final_params))
+
+
+def _spans(stats, name):
+    return [e for e in stats["spans"] if e["ph"] == "X"
+            and e["name"] == name]
+
+
+def test_profiled_run_puts_spans_on_the_host_plane(profiled):
+    (stats, host, _), _ = profiled
+    for name in ("worker.grad", "worker.rpc", "master.apply",
+                 "mailbox.drain"):
+        assert name in host, name
+    # stats_out["spans"] holds the same spans as the profile
+    ring = [e["name"] for e in stats["spans"] if e["ph"] == "X"
+            and e["name"].startswith(HOT_SPANS)]
+    assert sorted(ring) == sorted(host)
+    assert validate_chrome_trace({"traceEvents": stats["spans"]}) == []
+    cats = {e["name"]: e["cat"] for e in stats["spans"] if e["ph"] == "X"}
+    assert cats["worker.grad"] == "worker"
+    assert cats["master.apply"] == "master"
+    assert cats["mailbox.drain"] == "mailbox"
+    assert not [e for e in stats["spans"] if e["name"] == "mailbox.put"]
+
+
+def test_profiler_alone_starts_no_publisher(tmp_path, no_publisher):
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        stats = {}
+        _pinned_run(stats, grads=6)
+    finally:
+        jax.profiler.stop_trace()
+    assert no_publisher == []
+    assert _spans(stats, "worker.grad")
+    assert "obs_series" not in stats
+
+
+def test_profiler_off_records_nothing(no_publisher):
+    assert not trace.enabled
+    before = len(trace.events())
+    stats = {}
+    _pinned_run(stats, grads=6)
+    assert "spans" not in stats
+    assert len(trace.events()) == before
+    assert no_publisher == []
+    assert not trace.enabled
+
+
+def test_spans_of_one_gradient_share_its_identity(profiled):
+    (stats, _, _), _ = profiled
+    rpc = {(e["args"]["worker"], e["args"]["seq"]): e["args"]["step"]
+           for e in _spans(stats, "worker.rpc") if "step" in e["args"]}
+    assert sorted(rpc.values()) == list(range(1, 25))
+    for name in ("worker.next_batch", "worker.grad"):
+        ids = [(e["args"]["worker"], e["args"]["seq"])
+               for e in _spans(stats, name)]
+        assert len(ids) == len(set(ids))
+        assert set(rpc) <= set(ids)
+    applies = sorted(_spans(stats, "master.apply"), key=lambda e: e["ts"])
+    steps = [e["args"]["step"] for e in applies]
+    assert steps == sorted(steps) == list(range(1, 25))
+    for e in applies:                  # one gradient per drain here
+        a = e["args"]
+        assert e["args"]["k"] == 1
+        assert rpc[(a["worker"], a["seq"])] == a["step"]
+    for e in _spans(stats, "master.stack"):
+        a = e["args"]
+        assert (a["worker"], a["seq"]) in rpc
+    # each worker's gradients come back in its own order
+    for w in (0, 1):
+        mine = sorted((s, st) for (ww, s), st in rpc.items() if ww == w)
+        assert [st for _, st in mine] == sorted(st for _, st in mine)
+
+
+def test_in_flight_counted_and_params_unchanged_by_the_profiler(profiled):
+    (stats, _, params_on), (stats_off, params_off) = profiled
+    in_flight = [e["args"]["in_flight"]
+                 for e in _spans(stats, "master.apply")]
+    assert len(in_flight) == 24
+    assert all(isinstance(n, int) and 0 <= n <= 8 for n in in_flight)
+    _assert_params_equal(params_on, params_off)
+
+
+def test_compile_counter_lists_the_worker_program(profiled):
+    def fresh_grad(params, batch):      # a function no run has traced
+        return GRAD_FN(params, batch)
+
+    stats = {}
+    _pinned_run(stats, grad_fn=fresh_grad, grads=4)
+    comp = stats["compile"]
+    names = [name for name, secs, at in comp["in_call"]]
+    assert "<lambda>" in names          # runtime.flat_grad_program
+    assert all(secs >= 0 and at >= 0 for _, secs, at in comp["in_call"])
+    assert comp["count"] >= 1
+    assert comp["seconds"] >= sum(s for _, s, _ in comp["in_call"]) - 1e-9
+    # the profiled run put its compiles on the ring as spans
+    (profiled_stats, _, _), _ = profiled
+    kinds = {e["name"] for e in profiled_stats["spans"]
+             if e.get("cat") == "compile"}
+    assert kinds <= {"compile.trace", "compile.lower", "compile.backend"}
+
+
+def test_worker_and_receive_programs_carry_stable_scopes():
+    from repro.cluster.master import fused_flat_program
+    from repro.cluster.runtime import flat_grad_program
+    from repro.kernels.flat_update import FlatAlgorithm
+
+    fa = FlatAlgorithm(make_algorithm("dana-zero", HP))
+    flat = fa.init(PARAMS0, 2)
+    rows = fa.spec.rows
+    sds = jax.ShapeDtypeStruct
+    worker = flat_grad_program(fa.spec, GRAD_FN).lower(
+        sds((rows, 128), jnp.float32), TASK.batch(0, 0))
+    receive = fused_flat_program(fa, 1, False).lower(
+        flat, sds((1,), jnp.int32), sds((1,), jnp.float32),
+        sds((1, rows, 128), jnp.float32), None)
+    for lowered, module, scope in ((worker, "jit__lambda", "worker_grad"),
+                                   (receive, "jit_fused", "receive")):
+        assert lowered.as_text().startswith(f"module @{module} ")
+        assert f"/{scope}/" in lowered.as_text(debug_info=True)

@@ -79,7 +79,7 @@ def _drive_single(name, n):
     for ids, seed in BATCHES:
         k = len(ids)
         fn = master._get_fused_flat(k, False)
-        st, views, _, _ = fn(st, jnp.asarray(ids, jnp.int32),
+        st, views, _, _, _ = fn(st, jnp.asarray(ids, jnp.int32),
                              jnp.zeros((k,), jnp.float32),
                              jnp.stack([spec.pack(g)
                                         for g in _grads(k, seed)]),
