@@ -167,16 +167,15 @@ def master_capacity_row(algo_name: str, num_workers: int, k: int,
         fn = master._get_fused_flat(k, telemetry=False)
         bench_state = master._flat_state
         # flat wire format: workers push ALREADY-packed (R, 128) grads
-        # (their grad jit packs at their end); the serve loop stacks the
-        # batch into ONE (k, R, 128) device buffer before the fused pass
+        # (their grad jit packs at their end); the fused pass takes the
+        # k of them unstacked and stacks them inside its own jit
         grad = master._flat_algo.spec.pack(grad)
     else:
         fn = master._get_fused(k, telemetry=False)
         bench_state = state
     ids = jnp.asarray([j % num_workers for j in range(k)], jnp.int32)
     nows = jnp.zeros((k,), jnp.float32)
-    grads = (jnp.stack([grad] * k) if path == "flat"
-             else tuple(grad for _ in range(k)))
+    grads = tuple(grad for _ in range(k))
 
     # the flat fused pass DONATES its state (in-place kernel update), so
     # the state threads through continuously instead of resetting per

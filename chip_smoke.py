@@ -165,7 +165,7 @@ def compile_programs(grad_fn, algo, workers: int, k: int, batch: int,
                      seq: int):
     """Compile the two programs ``run_cluster`` runs on this path, built
     by the runtime's own builders: the master's fused receive
-    (``fused_flat_program``, a k-message stacked wire, state donated) and
+    (``fused_flat_program``, k unstacked gradients, state donated) and
     the worker's backward->wire program (``flat_grad_program``, view
     donated).  The run finds both in the persistent compile cache.
     Returns the receive program's HLO text; prints compile times and
@@ -185,7 +185,7 @@ def compile_programs(grad_fn, algo, workers: int, k: int, batch: int,
     programs = [
         ("fused receive", fused_flat_program(fa, k, False),
          (flat, sds((k,), jnp.int32), sds((k,), jnp.float32),
-          sds((k, rows, 128), jnp.float32), None)),
+          tuple(sds((rows, 128), jnp.float32) for _ in range(k)), None)),
         ("worker backward->wire", flat_grad_program(fa.spec, grad_fn, (0,)),
          (sds((rows, 128), jnp.float32), sds((batch, seq), jnp.int32))),
     ]

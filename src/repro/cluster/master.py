@@ -91,15 +91,18 @@ def run_serve_loop(server):
     servers, None on the tree path).
 
     ``server.busy_s`` accumulates the serve thread's host time inside
-    ``_apply``: stacking, id/time transfers, the dispatch of the receive
-    and the replies.  The receive itself runs on the device
-    asynchronously, so this is dispatch time, not device time.  When
+    ``_apply``: id/time transfers (and the sharded servers' eager
+    stack), the flat master's ``RUN_AHEAD`` wait, the dispatch of the
+    receive and the replies.  The receive itself runs on the device
+    asynchronously, so this is host time, not device time.  When
     tracing is enabled each receive is a ``<obs_cat>.apply`` span
     (``master.apply`` or ``shard.apply``) with its size ``k``, its first
     apply ``step``, the first gradient's ``(worker, seq)`` and, where
     the server counts them, ``in_flight``: the earlier receives the
-    device had not finished when this one was dispatched.  Pull replies
-    are ``<obs_cat>.pull`` spans.
+    device had not finished when this one was dispatched, and
+    ``stacked``: the gradients the receive program concatenated (0 when
+    it read its one gradient in place).  Pull replies are
+    ``<obs_cat>.pull`` spans.
 
     Chunks additionally never straddle an eval boundary
     (``server.eval_boundary``, 0 when no eval is configured): evals run
@@ -159,11 +162,9 @@ def run_serve_loop(server):
                         mx.slab_rows_streamed.add(u * rows2)
                         mx.slab_rows_total.add(n_slab * rows2)
                 if tr:
-                    in_flight = getattr(server, "in_flight", None)
-                    if in_flight is None:
-                        trace.end()
-                    else:
-                        trace.end(in_flight=in_flight)
+                    trace.end(**{a: v for a in ("in_flight", "stacked")
+                                 if (v := getattr(server, a, None))
+                                 is not None})
             if pulls and mx is not None:
                 mx.pulls.add(len(pulls))
             for m in pulls:
@@ -192,6 +193,15 @@ def run_serve_loop(server):
                 m.respond(None)
 
 
+# Unfinished receives the master lets the device queue hold when it
+# dispatches another.  Each holds a gradient and a reply view, two
+# state-sized buffers, and where the device bounds the run a deeper
+# queue adds memory and no throughput.  The runtime itself held the
+# master at two to three while an eager stack program preceded each
+# receive; without that program it lets about five queue up.
+RUN_AHEAD = 2
+
+
 def fused_flat_program(fa, k: int, telemetry: bool):
     """The master's fused receive for a k-message drain of
     ``FlatAlgorithm`` ``fa``: ``jit(flat, ids, nows, g_flat, views) ->
@@ -202,12 +212,13 @@ def fused_flat_program(fa, k: int, telemetry: bool):
     tells, without a sync, whether the device has finished this receive.
 
     Everything on the wire is already flat, and the batch arrives
-    STACKED: ``g_flat`` (and ``views`` under telemetry) is one
-    (k, R, 128) buffer — the caller stacks outside the jit (a single
-    dispatch on the threaded backend; the process backend stages the
-    k shared-memory grads into one host buffer and ships ONE
-    transfer).  The returned views are raw (R, 128) hat rows — the
-    master thread does no pytree work at all.
+    UNSTACKED: ``g_flat`` (and ``views`` under telemetry) is a tuple of
+    k (R, 128) arrays, the drained messages' own buffers.  The program
+    forms the kernel's (k, R, 128) operand itself: at k = 1 that adds a
+    unit leading axis, a bitcast of the same bytes, so the kernel reads
+    the gradient in place; at k > 1 it is one concatenation inside this
+    program, with no dispatch of its own.  The returned views are raw
+    (R, 128) hat rows — the master thread does no pytree work at all.
     """
     inv_sqrt_p = 1.0 / float(np.sqrt(fa.spec.n_elems))
 
@@ -217,12 +228,19 @@ def fused_flat_program(fa, k: int, telemetry: bool):
         # (None for snapshot-free members)
         stals = (fa.batch_staleness(flat, ids, k) if telemetry
                  else None)
+        # k == 1: a bitcast, the kernel reads the gradient in place.
+        # The barrier keeps the stacked operand a buffer of its own, as
+        # an eagerly stacked one is: without it XLA's CPU backend fuses
+        # the stack into the jnp reference's update and rounds it apart
+        # from the sharded and process servers' programs.  The Pallas
+        # kernel reads its operand whole either way.
+        g_flat = jax.lax.optimization_barrier(jnp.stack(g_flat))
         flat, hats, pres = fa.apply_batch(flat, ids, g_flat, nows,
                                           telemetry=telemetry)
         out_views = tuple(hats[j] for j in range(k))
         done = flat["theta"][0, 0]
         if telemetry:
-            d = pres - views             # zero in the padding region
+            d = pres - jnp.stack(views)  # zero in the padding region
             gaps = jnp.sqrt(jnp.sum(d * d, axis=(1, 2))) * inv_sqrt_p
             gnorms = jnp.sqrt(jnp.sum(g_flat * g_flat, axis=(1, 2)))
             return flat, out_views, gaps, gnorms, stals, done
@@ -334,14 +352,18 @@ class Master:
         self._steady_mark = max(1, total_grads // 5)
         self.steady_t: float | None = None
         # the master thread's host time in _apply (drain waits excluded):
-        # stacking, transfers, the receive's dispatch and the replies.
-        # The receive runs asynchronously, so this is dispatch time, not
-        # device time
+        # transfers, the RUN_AHEAD wait, the receive's dispatch and the
+        # replies.  The receive runs asynchronously, so this is host
+        # time, not device time
         self.busy_s = 0.0
-        # traced runs only: the ``done`` scalars of the last receives,
-        # and how many of them were unfinished at the latest dispatch
-        self._done: collections.deque = collections.deque(maxlen=8)
+        # flat path: the ``done`` scalars of the last receives, newest
+        # last (the RUN_AHEAD bound waits on the oldest); traced runs
+        # also record how many were unfinished at the latest dispatch
+        # and how many gradients that receive's program concatenated
+        self._done: collections.deque = collections.deque(
+            maxlen=RUN_AHEAD + 1)
         self.in_flight: int | None = None
+        self.stacked: int | None = None
 
     # -- worker-visible state -------------------------------------------
     @property
@@ -394,12 +416,9 @@ class Master:
         while k <= self.coalesce:
             ids = jax.ShapeDtypeStruct((k,), jnp.int32)
             nows = jax.ShapeDtypeStruct((k,), jnp.float32)
-            if self.state_is_flat:
-                # stacked wire format: one (k, R, 128) buffer per batch
-                grads = jax.ShapeDtypeStruct((k,) + theta.shape,
-                                             theta.dtype)
-            else:
-                grads = tuple(params for _ in range(k))
+            # the wire format: the k drained gradients as they arrive
+            one = abstract(theta) if self.state_is_flat else params
+            grads = tuple(one for _ in range(k))
             views = grads if self.record_telemetry else None
             fn, st = self._fused_for(k, self.record_telemetry)
             fn.lower(st, ids, nows, grads, views).compile()
@@ -479,28 +498,22 @@ class Master:
         ids = jnp.asarray([m.worker_id for m in work], jnp.int32)
         nows = jnp.asarray([m.t_send for m in work], jnp.float32)
         tr = trace.enabled
+        # the drained gradients go to the receive as they are: the flat
+        # program stacks them itself (in place at k == 1)
+        grads = tuple(m.grad for m in work)
+        views = tuple(m.view for m in work) if telemetry else None
         if self.state_is_flat:
-            # stacked wire format: ONE (k, R, 128) buffer per batch (one
-            # concatenate dispatch here; the process backend stages into
-            # a preallocated host buffer and ships a single transfer)
+            if len(self._done) > RUN_AHEAD:
+                # at most RUN_AHEAD earlier receives stay unfinished
+                self._done[0].block_until_ready()
             if tr:
-                trace.begin("master.stack", "master", k=k,
-                            worker=work[0].worker_id, seq=work[0].seq)
-            grads = jnp.stack([m.grad for m in work])
-            views = (jnp.stack([m.view for m in work]) if telemetry
-                     else None)
-            if tr:
-                trace.end()
                 self.in_flight = sum(not d.is_ready() for d in self._done)
-        else:
-            grads = tuple(m.grad for m in work)
-            views = tuple(m.view for m in work) if telemetry else None
+                self.stacked = 0 if k == 1 else k
         t0 = self._step
         out = fn(st, ids, nows, grads, views)
         if self.state_is_flat:
             *out, done = out
-            if tr:
-                self._done.append(done)
+            self._done.append(done)
         if telemetry:
             st, out_views, gaps, gnorms, stals = out
         else:

@@ -346,9 +346,10 @@ class _ShardServer:
 
         def fused(flat, ids, nows, g, views):
             # stacked wire format: g (and views) arrive as ONE
-            # (k, rows, 128) buffer, stacked outside the jit (see
-            # Master._get_fused_flat); under rebalancing the stack is
-            # full-height and this shard's current rows slice off here
+            # (k, rows, 128) buffer, stacked outside the jit (the single
+            # master's fused_flat_program stacks inside its own); under
+            # rebalancing the stack is full-height and this shard's
+            # current rows slice off here
             if rows is not None:
                 g = g[:, rows[0]:rows[1]]
             flat, hats, pres = fa.apply_batch(flat, ids, g, nows,

@@ -728,12 +728,15 @@ class FlatAlgorithm:
         telemetry; zeros when absent).
         Returns (flat', hats (k,R,128), thetas_pre or None).
 
-        The stacked ``g_flat`` IS the wire format: every serve loop
-        (single, sharded, process) stacks its drained batch into one
-        contiguous (k, R, 128) buffer on the host side — the process
-        backend stages shm ring slices into a pinned buffer and ships
-        ONE device transfer per batch — so no fused closure ever
-        re-stacks k separate arrays inside jit.
+        ``g_flat`` is the kernel's operand, one contiguous (k, R, 128)
+        buffer; where it is formed depends on the serve loop.  The
+        threaded master's fused receive (``fused_flat_program``) gets
+        the k drained (R, 128) gradients unstacked and stacks them
+        inside its own jit: at k = 1 a bitcast, so the kernel reads the
+        gradient in place.  The sharded servers stack eagerly before
+        their receive, and the process backend stages its shared-memory
+        ring slices into one host buffer and ships ONE device transfer
+        per batch.
         """
         k = g_flat.shape[0]
         if (self.fam.gap_aware and self.spec is not None

@@ -165,6 +165,41 @@ def test_coalesced_pass_matches_sequential_receive():
             _assert_close_to_ref(a, r)
 
 
+def test_flat_master_bounds_its_run_ahead():
+    """The flat master dispatches a receive only while at most RUN_AHEAD
+    earlier ones are unfinished: it waits on the oldest one's ``done``
+    scalar first, so the device queue, and the gradients and views it
+    holds, stays RUN_AHEAD + 1 receives deep."""
+    from repro.cluster.mailbox import GradMsg
+    from repro.cluster.master import RUN_AHEAD
+
+    class Done:                        # a receive the device still runs
+        ready = False
+
+        def is_ready(self):
+            return self.ready
+
+        def block_until_ready(self):
+            self.ready = True
+            return self
+
+    _, _, m = _make_master("dana-zero", n=2, use_kernel=True)
+    dones = []
+
+    def receive(st, ids, nows, grads, views):
+        assert sum(not d.ready for d in dones) <= RUN_AHEAD
+        dones.append(Done())
+        return st, grads, None, None, dones[-1]
+
+    m._fused[("flat", 1, False)] = receive
+    g = m._flat_algo.spec.pack(_grads(1)[0])
+    n = 2 * RUN_AHEAD + 3
+    for seq in range(n):
+        m._apply([GradMsg(seq % 2, g, None, 0, 0.0, seq=seq)])
+    assert [d.ready for d in dones] == ([True] * (n - RUN_AHEAD - 1)
+                                       + [False] * (RUN_AHEAD + 1))
+
+
 def test_kernel_routing_matches_algorithm_path():
     """All three master paths — generic tree, PR 1's legacy per-message
     dana_update kernel (flat=False), and the batched flat kernel — must
@@ -187,7 +222,7 @@ def test_kernel_routing_matches_algorithm_path():
                                                    None)
     s_f, v_f, _, _, _ = m_flat._get_fused_flat(k, False)(
         m_flat._flat_state, ids, nows,
-        jnp.stack([spec.pack(g) for g in grads]), None)  # stacked wire
+        tuple(spec.pack(g) for g in grads), None)  # unstacked wire
     v_f = tuple(spec.unpack(v) for v in v_f)
     s_f = m_flat._flat_algo.tree_state(s_f)
     s_r, v_r = _eager_receive_sends(algo, state, ids, grads, nows)

@@ -369,8 +369,7 @@ def _check_flat_vs_tree(name, ids_l, schedule=None, k_batch=None,
         s_t, vt, _, _ = m_tree._get_fused(k, False)(s_t, ids, nows,
                                                     chunk, None)
         s_f, vf, _, _, _ = m_flat._get_fused_flat(k, False)(
-            s_f, ids, nows, jnp.stack([spec.pack(g) for g in chunk]),
-            None)
+            s_f, ids, nows, tuple(spec.pack(g) for g in chunk), None)
         v_t.extend(vt)
         v_f.extend(spec.unpack(v) for v in vf)
     tree_f = m_flat._flat_algo.tree_state(s_f)
@@ -454,8 +453,8 @@ def test_flat_fused_telemetry_matches_tree():
                                                        grads, views)
     _, _, gaps_f, gn_f, _, _ = m_flat._get_fused_flat(k, True)(
         m_flat._flat_state, ids, nows,
-        jnp.stack([spec.pack(g) for g in grads]),
-        jnp.stack([spec.pack(v) for v in views]))
+        tuple(spec.pack(g) for g in grads),
+        tuple(spec.pack(v) for v in views))
     np.testing.assert_allclose(np.asarray(gaps_f), np.asarray(gaps_t),
                                rtol=1e-5, atol=1e-7)
     np.testing.assert_allclose(np.asarray(gn_f), np.asarray(gn_t),
@@ -672,6 +671,82 @@ def test_gap_pallas_through_flat_algorithm():
 
 
 # ---------------------------------------------------------------------------
+# the unstacked wire: the fused receive stacks the drained gradients
+# inside its own jit, in place at k = 1
+# ---------------------------------------------------------------------------
+def _stacked_receive(fa, k, telemetry):
+    """The receive on a pre-stacked (k, R, 128) wire: ``apply_batch``
+    on the one buffer the serve loop stacked before the dispatch."""
+    inv_sqrt_p = 1.0 / float(np.sqrt(fa.spec.n_elems))
+
+    def receive(flat, ids, nows, g, views):
+        flat, hats, pres = fa.apply_batch(flat, ids, g, nows,
+                                          telemetry=telemetry)
+        hats = tuple(hats[j] for j in range(k))
+        if not telemetry:
+            return flat, hats, None, None
+        d = pres - views
+        return (flat, hats, jnp.sqrt(jnp.sum(d * d, axis=(1, 2)))
+                * inv_sqrt_p, jnp.sqrt(jnp.sum(g * g, axis=(1, 2))))
+
+    return jax.jit(receive)
+
+
+@pytest.mark.parametrize("telemetry", [False, True])
+@pytest.mark.parametrize("k", [1, 2, 4])
+def test_unstacked_receive_equals_stacked_apply_batch(k, telemetry):
+    from repro.cluster.master import fused_flat_program
+
+    fa = FlatAlgorithm(make_algorithm("dana-zero", HP))
+    flat_a, flat_b = fa.init(PARAMS0, 4), fa.init(PARAMS0, 4)
+    ids = jnp.asarray([1, 3, 1, 0][:k], jnp.int32)
+    nows = jnp.zeros((k,), jnp.float32)
+    grads = tuple(fa.spec.pack(g) for g in _grads(k, seed=17))
+    views = (tuple(fa.spec.pack(jax.tree.map(lambda l: l + 0.01 * j,
+                                             PARAMS0))
+                   for j in range(k)) if telemetry else None)
+    got = fused_flat_program(fa, k, telemetry)(
+        flat_a, ids, nows, grads, views)[:4]
+    want = _stacked_receive(fa, k, telemetry)(
+        flat_b, ids, nows, jnp.stack(grads),
+        jnp.stack(views) if telemetry else None)
+    _assert_trees_equal(got, want)
+
+
+def test_one_gradient_reaches_the_update_without_a_copy():
+    """At k = 1 the gradient parameter of the lowered receive is only
+    reshaped or broadcast to (1, R, 128), a bitcast of the same bytes:
+    the program holds no concatenation of the gradient."""
+    import re
+
+    from repro.cluster.master import fused_flat_program
+
+    fa = FlatAlgorithm(make_algorithm("dana-zero", HP))
+    flat = fa.init(PARAMS0, 2)
+    rows = fa.spec.rows
+    sds = jax.ShapeDtypeStruct
+    text = fused_flat_program(fa, 1, False).lower(
+        flat, sds((1,), jnp.int32), sds((1,), jnp.float32),
+        (sds((rows, 128), jnp.float32),), None).as_text()
+    sig = text[text.index("func.func public @main("):]
+    sig = sig[:sig.index(") -> (")]
+    # the one (R, 128) parameter that is not donated state
+    grad = [a for a, attrs in re.findall(
+        rf"(%arg\d+): tensor<{rows}x128xf32>( \{{[^}}]*\}})?", sig)
+        if "tf.aliasing_output" not in attrs]
+    assert len(grad) == 1, sig
+    uses = [ln.strip() for ln in text.splitlines()[1:]
+            if re.search(rf"{grad[0]}\b", ln)
+            and not ln.lstrip().startswith("func.func")]
+    assert uses
+    for ln in uses:
+        assert re.search(rf"= stablehlo\.(broadcast_in_dim|reshape) "
+                         rf"{grad[0]}\b", ln), ln
+        assert f"-> tensor<1x{rows}x128xf32>" in ln, ln
+    assert "stablehlo.concatenate" not in text
+
+
+# ---------------------------------------------------------------------------
 # buffer donation: the fused pass updates state in place
 # ---------------------------------------------------------------------------
 def test_flat_fused_donates_and_aliases_buffers():
@@ -687,7 +762,7 @@ def test_flat_fused_donates_and_aliases_buffers():
     ptr_v = st["v"].unsafe_buffer_pointer()
     ids = jnp.asarray([0, 1, 2, 3], jnp.int32)
     nows = jnp.zeros((4,), jnp.float32)
-    grads = jnp.stack([spec.pack(g) for g in _grads(4, seed=31)])
+    grads = tuple(spec.pack(g) for g in _grads(4, seed=31))
     out_state, _, _, _, _ = fn(st, ids, nows, grads, None)
     assert out_state["theta"].unsafe_buffer_pointer() == ptr_theta
     assert out_state["v"].unsafe_buffer_pointer() == ptr_v
@@ -706,7 +781,7 @@ def test_pull_views_survive_donation():
     m._flat_state, _, _, _, _ = fn(
         m._flat_state, jnp.asarray([0], jnp.int32),
         jnp.zeros((1,), jnp.float32),
-        spec.pack(_grads(1, seed=5)[0])[None], None)
+        (spec.pack(_grads(1, seed=5)[0]),), None)
     np.testing.assert_array_equal(np.asarray(view), before)
 
 

@@ -81,8 +81,7 @@ def _drive_single(name, n):
         fn = master._get_fused_flat(k, False)
         st, views, _, _, _ = fn(st, jnp.asarray(ids, jnp.int32),
                              jnp.zeros((k,), jnp.float32),
-                             jnp.stack([spec.pack(g)
-                                        for g in _grads(k, seed)]),
+                             tuple(spec.pack(g) for g in _grads(k, seed)),
                              None)
         out.extend(views)
     master._flat_state = st

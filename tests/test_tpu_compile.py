@@ -102,6 +102,56 @@ def test_receive_kernel_compiles(chip, rows, kernel, hat_mode):
     _compile(fn, chip, *_receive_shapes(rows, weighted))
 
 
+@pytest.mark.parametrize("k", [1, K])
+def test_fused_receive_reads_one_gradient_in_place(chip, rows, k,
+                                                   monkeypatch):
+    """The master's fused receive compiled for the chip: at k = 1 the
+    kernel's (1, R, 128) gradient operand is a bitcast of the gradient
+    parameter, so no state-sized copy or fusion precedes the kernel; at
+    k > 1 the one concatenation happens inside the program."""
+    import re
+
+    import repro.kernels.flat_update.ops as ops
+    from repro.cluster.master import fused_flat_program
+    from repro.core import HyperParams, make_algorithm
+    from repro.kernels.flat_update import FlatAlgorithm
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)   # no interpret mode
+    grad_fn = ModelGradFn(chip_smoke.MODEL, reduced=False,
+                          overrides=chip_smoke.OVERRIDES)
+    fa = FlatAlgorithm(make_algorithm("dana-zero", HyperParams(
+        lr=0.05, momentum=0.9)), use_pallas=True)
+    params = jax.eval_shape(grad_fn.init, jax.random.PRNGKey(0))
+    flat = jax.eval_shape(lambda p: fa.init(p, N), params)
+    assert fa.spec.rows == rows
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    text = fused_flat_program(fa, k, False).lower(
+        jax.tree.map(lambda x: sds(x.shape, x.dtype), flat),
+        sds((k,), jnp.int32), sds((k,), jnp.float32),
+        tuple(sds((rows, 128), jnp.float32) for _ in range(k)),
+        None).compile().as_text()
+    call = [ln for ln in text.splitlines()
+            if "custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(call) == 1
+    # the kernel's gradient operand stays f32[k,R,128]
+    assert re.findall(r"f32\[(\d+),\d+,128\]",
+                      call[0][call[0].index("custom-call("):])[-1] == str(k)
+    made = [ln.strip() for ln in text.splitlines()
+            if re.search(rf"= f32\[(\d+,)?{rows},128\]\S* "
+                         rf"(copy|fusion|concatenate)\(", ln)]
+    if k == 1:
+        assert made == []
+        grad = re.search(r"(%\S+) = f32\[\d+,128\]\S* parameter\(\d+\).*"
+                         r'op_name="g_flat\[0\]"', text).group(1)
+        assert re.search(rf"= f32\[1,{rows},128\]\S* bitcast\({grad}\)",
+                         text)
+    else:
+        assert len(made) == 1 and "receive/concatenate" in made[0]
+
+
 @pytest.mark.parametrize("n", [1, N])
 def test_send_kernel_compiles(chip, rows, n):
     fn = functools.partial(_send_view_pallas, u2=None, eps=1e-8,
